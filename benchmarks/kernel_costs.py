@@ -40,8 +40,8 @@ def main():
                  f"tpu_v5e_roofline_us={est:.1f}"))
     # paged attention decode
     N, page, mp, Bd = 64, 16, 16, 8
-    kp = jax.random.normal(ks[0], (N, page, Hkv, D), jnp.float32)
-    vp = jax.random.normal(ks[1], (N, page, Hkv, D), jnp.float32)
+    kp = jax.random.normal(ks[0], (Hkv, N, page, D), jnp.float32)
+    vp = jax.random.normal(ks[1], (Hkv, N, page, D), jnp.float32)
     qd = jax.random.normal(ks[2], (Bd, Hq, D), jnp.float32)
     tabs = jnp.tile(jnp.arange(mp, dtype=jnp.int32), (Bd, 1))
     lens = jnp.full((Bd,), mp * page, jnp.int32)

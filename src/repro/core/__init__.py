@@ -13,7 +13,8 @@ from repro.core.events import (  # noqa: F401
     TokenEvent,
 )
 from repro.core.executor import (  # noqa: F401
-    Executor, KernelExecutor, PerfModelExecutor, StepOutputs,
+    DeviceExecutor, Executor, PerfModelExecutor, StepOutputs,
+    configure_compile_cache,
 )
 from repro.core.preemption import (  # noqa: F401
     DEFAULT_PREEMPTION, PreemptionPolicy,

@@ -7,8 +7,8 @@ core/events.py) splits the historical monolithic engines into
     read-only ``SchedView``, returns a ``StepPlan`` (admissions,
     rejections, lane launches, timed retries);
   * an ``Executor`` — prices the launched steps (default
-    ``PerfModelExecutor``; a real-kernel executor slots in behind the
-    same interface);
+    ``PerfModelExecutor``) or runs them on a device
+    (``DeviceExecutor``) behind the same interface;
   * this ``Engine`` — the substrate: queues, decode-owned paged-KV
     pools, the event loop, preemption, KV transfers, and a typed
     request-lifecycle **event stream** (``TokenEvent`` / ``PhaseEvent``
@@ -126,7 +126,12 @@ class Engine:
             else make_scheduler(serve.mode, cfg, serve, hw)
         self.preempt_policy = preempt_policy
         sched = self.scheduler
-        pools = sched.pool_blocks(cfg, serve, hw)
+        # an executor that holds real KV memory sizes the decode pool from
+        # it; otherwise the scheduler's HBM model does
+        device_pool = executor.pool_blocks() \
+            if executor is not None else None
+        pools = {"decode": device_pool} if device_pool is not None \
+            else sched.pool_blocks(cfg, serve, hw)
         # session prefix cache budget: inert unless requests carry
         # session ids AND the topology keeps KV resident across turns
         # (colocated join-route engines; disagg decode KV is freed on
@@ -218,7 +223,8 @@ class Engine:
                                self._lane_f[ln]) for ln in sched.lanes}
         return SchedView(now=self.loop.now, serve=self.serve,
                          queues=self.queues, running=self.running,
-                         kv=self.kv, kv_p=self.kv_p, lanes=lanes, wake=wake)
+                         kv=self.kv, kv_p=self.kv_p, lanes=lanes, wake=wake,
+                         max_context=self.executor.max_context)
 
     def _wake(self, wake: Wake) -> None:
         view = self._view(wake)
@@ -304,6 +310,14 @@ class Engine:
                 plan.decode.joins = [r for r in plan.decode.joins
                                      if r.rid not in failed_admits]
         outs = self.executor.execute(plan, view)
+        # durations count from the call; on a real-time clock the time
+        # ``execute`` spent blocked has already passed and is not added
+        # again (the virtual clock does not move during the call)
+        elapsed = self.loop.now - now
+
+        def after(d: float, fn) -> None:
+            self.loop.after(max(0.0, d - elapsed), fn)
+
         if plan.prefill is not None:
             batch = plan.prefill.batch
             q = self.queues[plan.prefill.queue]
@@ -322,8 +336,8 @@ class Engine:
             self._lane_cost["prefill"] = outs.prefill.cost
             self.inflight_prefill_tokens = sum(r.prefill_tokens_needed
                                                for r in batch)
-            self.loop.after(outs.prefill.duration_s,
-                            lambda b=batch: self._prefill_done(b))
+            after(outs.prefill.duration_s,
+                  lambda b=batch: self._prefill_done(b))
         if plan.decode is not None:
             for r in plan.decode.joins:
                 self.queues["pending_join"].remove(r)
@@ -334,15 +348,15 @@ class Engine:
             self._lane_cost["decode"] = outs.decode.cost
             self._lane_f["decode"] = plan.decode.f_decode
             batch = list(self.running)
-            self.loop.after(outs.decode.duration_s,
-                            lambda b=batch: self._decode_done(b))
+            after(outs.decode.duration_s,
+                  lambda b=batch: self._decode_done(b))
         if plan.hybrid is not None:
             self._lane_busy["step"] = True
             self._lane_cost["step"] = outs.hybrid.cost
             batch = list(self.running)
             chunks = plan.hybrid.chunks
-            self.loop.after(outs.hybrid.duration_s,
-                            lambda b=batch, c=chunks: self._step_done(b, c))
+            after(outs.hybrid.duration_s,
+                  lambda b=batch, c=chunks: self._step_done(b, c))
         for retry in plan.retries:
             self.loop.after(
                 retry.delay_s,
@@ -804,11 +818,12 @@ class RapidEngine(Engine):
     def __init__(self, cfg, serve: ServeConfig, hw: HardwareSpec = TPU_V5E,
                  avg_ctx_hint: int = 4096,
                  loop: Optional[EventLoop] = None,
-                 preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION):
+                 preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION,
+                 executor: Optional[Executor] = None):
         super().__init__(
             cfg, serve, hw,
             scheduler=RapidScheduler(cfg, serve, hw, avg_ctx_hint),
-            loop=loop, preempt_policy=preempt_policy)
+            executor=executor, loop=loop, preempt_policy=preempt_policy)
 
 
 class HybridEngine(Engine):
@@ -816,10 +831,12 @@ class HybridEngine(Engine):
 
     def __init__(self, cfg, serve: ServeConfig, hw: HardwareSpec = TPU_V5E,
                  loop: Optional[EventLoop] = None,
-                 preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION):
+                 preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION,
+                 executor: Optional[Executor] = None):
         super().__init__(cfg, serve, hw,
                          scheduler=HybridScheduler(cfg, serve, hw),
-                         loop=loop, preempt_policy=preempt_policy)
+                         executor=executor, loop=loop,
+                         preempt_policy=preempt_policy)
 
 
 class DisaggEngine(Engine):
@@ -827,10 +844,12 @@ class DisaggEngine(Engine):
 
     def __init__(self, cfg, serve: ServeConfig, hw: HardwareSpec = TPU_V5E,
                  loop: Optional[EventLoop] = None,
-                 preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION):
+                 preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION,
+                 executor: Optional[Executor] = None):
         super().__init__(cfg, serve, hw,
                          scheduler=DisaggScheduler(cfg, serve, hw),
-                         loop=loop, preempt_policy=preempt_policy)
+                         executor=executor, loop=loop,
+                         preempt_policy=preempt_policy)
 
 
 ENGINES = {
@@ -843,13 +862,15 @@ ENGINES = {
 def make_engine(mode: str, cfg, serve: ServeConfig,
                 hw: HardwareSpec = TPU_V5E,
                 loop: Optional[EventLoop] = None,
-                preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION
-                ) -> Engine:
+                preempt_policy: PreemptionPolicy = DEFAULT_PREEMPTION,
+                executor: Optional[Executor] = None) -> Engine:
+    """``executor`` defaults to the perfmodel; pass a ``DeviceExecutor``
+    to run the steps on a chip."""
     if mode not in ENGINES:
         raise KeyError(
             f"unknown engine mode {mode!r}; known: {sorted(ENGINES)}")
     return ENGINES[mode](cfg, serve, hw, loop=loop,
-                         preempt_policy=preempt_policy)
+                         preempt_policy=preempt_policy, executor=executor)
 
 
 def drive(engine: BaseEngine, requests: List[Request]

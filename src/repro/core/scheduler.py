@@ -107,6 +107,9 @@ class SchedView:
     kv_p: Optional[KVCacheManager]
     lanes: Mapping[str, LaneState]
     wake: Wake
+    # longest prompt + output one request may reach (the executor's
+    # per-request KV slot); None = bounded by the pool only
+    max_context: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -237,13 +240,17 @@ class Scheduler:
     # -- shared helpers ------------------------------------------------------
     @staticmethod
     def _fits_pool(prompt_len: int, kv: KVCacheManager,
-                   page_size: int) -> bool:
-        """Can the prompt *ever* fit this pool?"""
+                   page_size: int, max_context: Optional[int] = None
+                   ) -> bool:
+        """Can the prompt *ever* fit this pool (and one request slot)?"""
+        if max_context is not None and prompt_len > max_context:
+            return False
         return kv_pages_for(prompt_len, page_size) <= kv.allocator.num_blocks
 
     @staticmethod
     def _lifetime_cap(r: Request, kv: KVCacheManager,
-                      page_size: int) -> Optional[int]:
+                      page_size: int, max_context: Optional[int] = None
+                      ) -> Optional[int]:
         """Colocated pools: cap for the single-request decode stall
         (ROADMAP item 5).  A prompt that fits but whose prompt+output
         never will would, once running alone, self-preempt on every
@@ -252,9 +259,12 @@ class Scheduler:
         Generating N tokens appends N-1 tokens of KV beyond the prompt
         (the first token comes out of prefill; the last token's KV is
         never appended), so the exact bound is
-        ``prompt + max_new - 1 <= pool_tokens``.  Returns the cap, or
-        None when the request already fits over its lifetime."""
+        ``prompt + max_new - 1 <= pool_tokens``; an executor with
+        per-request slots also bounds it by ``max_context``.  Returns the
+        cap, or None when the request already fits over its lifetime."""
         pool_tokens = kv.allocator.num_blocks * page_size
+        if max_context is not None:
+            pool_tokens = min(pool_tokens, max_context)
         if r.prompt_len + r.max_new_tokens - 1 <= pool_tokens:
             return None
         return pool_tokens - r.prompt_len + 1
@@ -319,7 +329,8 @@ class RapidScheduler(Scheduler):
             free = view.kv.available_blocks
             claimed = set()     # sessions whose parked prefix this plan
             for r in view.queues["waiting_kv"]:   # already hands out
-                if not self._fits_pool(r.prompt_len, view.kv, ps):
+                if not self._fits_pool(r.prompt_len, view.kv, ps,
+                                       view.max_context):
                     plan.rejects.append((r, "waiting_kv"))
                     continue
                 need = self._pages_needed(r, view.kv, ps, claimed)
@@ -329,7 +340,8 @@ class RapidScheduler(Scheduler):
                 plan.admits.append(Admission(
                     r, "waiting_kv", "waiting_prefill",
                     State.WAITING_PREFILL,
-                    truncate_to=self._lifetime_cap(r, view.kv, ps)))
+                    truncate_to=self._lifetime_cap(r, view.kv, ps,
+                                                   view.max_context)))
                 admitted.append(r)
         # -- prefill actor: whole prompts up to the token cap ------------
         if not view.lanes["prefill"].busy:
@@ -398,7 +410,8 @@ class HybridScheduler(Scheduler):
         admitted: List[Request] = []
         claimed = set()
         for r in view.queues["waiting"]:
-            if not self._fits_pool(r.prompt_len, view.kv, ps):
+            if not self._fits_pool(r.prompt_len, view.kv, ps,
+                                   view.max_context):
                 plan.rejects.append((r, "waiting"))
                 continue
             need = self._pages_needed(r, view.kv, ps, claimed)
@@ -409,7 +422,8 @@ class HybridScheduler(Scheduler):
             plan.admits.append(Admission(
                 r, "waiting", "chunking", State.PREFILLING,
                 stamp_prefill_start=True,
-                truncate_to=self._lifetime_cap(r, view.kv, ps)))
+                truncate_to=self._lifetime_cap(r, view.kv, ps,
+                                               view.max_context)))
             admitted.append(r)
         # -- Sarathi: budget filled with decodes first, then chunks ------
         bs = len(view.running)

@@ -7,15 +7,20 @@ streamed into VMEM for grid step (b, h, p) is physical page
 ``block_tables[b, p]`` — the TPU-native analogue of vLLM's gather, with
 no host-side KV reshuffle.
 
+Page layout: ``k_pages``/``v_pages`` are ``(Hkv, N, page, D)`` — KV head
+ahead of the page index, so a (page, D) tile is the minor two dims of
+the array and the (1, 1, page, D) block satisfies Mosaic's tiling rule
+for any page size.  A dense slot cache laid out ``(Hkv, B, S, D)``
+reshapes to this layout without a copy (``ops.paged_attention_dense``).
+
 Grid: (B, Hkv, max_pages); the page dim is innermost/sequential, carrying
 flash-style (m, l, acc) scratch for the G grouped query heads.
 
-VMEM working set per program (page=64, G<=8, D=128):
+VMEM working set per program (page=128, G=12, D=128):
     q     (G, D)        f32     k/v page (page, D)   bf16
     acc   (G, D)        f32     m, l     (G,)        f32
-well under budget; `page` is a multiple of 8 and D of 128 for clean
-(8,128) tiling.  Out-of-range pages (seq ended) are culled at block level
-via @pl.when, so short sequences cost only their own pages.
+well under budget.  Out-of-range pages (seq ended) are culled at block
+level via @pl.when, so short sequences cost only their own pages.
 """
 from __future__ import annotations
 
@@ -46,8 +51,8 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p * page < n)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale   # (G, page)
@@ -73,10 +78,10 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                     interpret: bool = False):
-    """q (B,Hq,D); k/v_pages (N,page,Hkv,D); block_tables (B,max_pages)
+    """q (B,Hq,D); k/v_pages (Hkv,N,page,D); block_tables (B,max_pages)
     int32; seq_lens (B,).  Returns (B,Hq,D)."""
     B, Hq, D = q.shape
-    N, page, Hkv, _ = k_pages.shape
+    Hkv, N, page, _ = k_pages.shape
     G = Hq // Hkv
     max_pages = block_tables.shape[1]
     # (B, Hkv, G, D) query layout: G grouped heads ride the sublane dim
@@ -93,10 +98,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         in_specs=[
             pl.BlockSpec((1, 1, G, D),
                          lambda b, h, p, tab, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, p, tab, lens: (tab[b, p], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, p, tab, lens: (tab[b, p], 0, h, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, p, tab, lens: (h, tab[b, p], 0, 0)),
+            pl.BlockSpec((1, 1, page, D),
+                         lambda b, h, p, tab, lens: (h, tab[b, p], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D),
                                lambda b, h, p, tab, lens: (b, h, 0, 0)),

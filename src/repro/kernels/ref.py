@@ -42,26 +42,26 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens):
     """Decode attention over a paged KV cache.
 
-    q (B,Hq,D); k/v_pages (N, page, Hkv, D); block_tables (B, max_pages)
+    q (B,Hq,D); k/v_pages (Hkv, N, page, D); block_tables (B, max_pages)
     int32; seq_lens (B,) = valid tokens per sequence (including the
     current token, already written to its slot).  Returns (B,Hq,D).
     """
     B, Hq, D = q.shape
-    N, page, Hkv, _ = k_pages.shape
+    Hkv, N, page, _ = k_pages.shape
     G = Hq // Hkv
     max_pages = block_tables.shape[1]
 
     def one(qb, tab, n):
-        # gather this sequence's pages -> (max_pages*page, Hkv, D)
-        kk = k_pages[tab].reshape(max_pages * page, Hkv, D)
-        vv = v_pages[tab].reshape(max_pages * page, Hkv, D)
+        # gather this sequence's pages -> (Hkv, max_pages*page, D)
+        kk = k_pages[:, tab].reshape(Hkv, max_pages * page, D)
+        vv = v_pages[:, tab].reshape(Hkv, max_pages * page, D)
         qg = qb.reshape(Hkv, G, D).astype(jnp.float32)
-        scores = jnp.einsum("hgd,khd->hgk", qg,
+        scores = jnp.einsum("hgd,hkd->hgk", qg,
                             kk.astype(jnp.float32)) / (D ** 0.5)
         valid = jnp.arange(max_pages * page) < n
         scores = jnp.where(valid[None, None], scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("hgk,khd->hgd", probs, vv.astype(jnp.float32))
+        out = jnp.einsum("hgk,hkd->hgd", probs, vv.astype(jnp.float32))
         return out.reshape(Hq, D)
 
     return jax.vmap(one)(q, block_tables, seq_lens).astype(q.dtype)

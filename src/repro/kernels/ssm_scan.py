@@ -39,10 +39,8 @@ def _ssm_kernel(xs_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
 
     A = a_ref[...]                       # (tile_d, ds)
 
-    # all ref indices go through pl.ds slices (never raw Python ints):
-    # interpret-mode's swap discharge rule only understands Slice objects.
     def _row(ref, t):
-        return pl.load(ref, (pl.ds(0, 1), pl.ds(t, 1), slice(None)))[0, 0]
+        return ref[0, pl.ds(t, 1), :][0]
 
     def step(t, h):
         dt_t = _row(dt_ref, t)           # (tile_d,)
@@ -52,16 +50,14 @@ def _ssm_kernel(xs_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
         a_t = jnp.exp(dt_t[:, None] * A)             # (tile_d, ds)
         h = a_t * h + (dt_t * x_t)[:, None] * b_t[None, :]
         y_t = jnp.sum(h * c_t[None, :], axis=1)      # (tile_d,)
-        pl.store(y_ref, (pl.ds(0, 1), pl.ds(t, 1), slice(None)),
-                 y_t[None, None])
+        y_ref[0, pl.ds(t, 1), :] = y_t[None]
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
 
     @pl.when(ci == num_chunks - 1)
     def _emit_state():
-        pl.store(hout_ref, (pl.ds(0, 1), slice(None), slice(None)),
-                 h_ref[...][None])
+        hout_ref[0] = h_ref[...]
 
 
 @functools.partial(jax.jit,

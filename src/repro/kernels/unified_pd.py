@@ -141,8 +141,8 @@ def _unified_kernel(desc_ref, tab_ref, lens_ref,
     @pl.when(d_needed)
     def _decode():
         q = qd_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = kpg_ref[0, :, 0].astype(jnp.float32)        # (page, D)
-        v = vpg_ref[0, :, 0].astype(jnp.float32)
+        k = kpg_ref[0, 0].astype(jnp.float32)           # (page, D)
+        v = vpg_ref[0, 0].astype(jnp.float32)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         sc *= sm_scale
@@ -181,7 +181,7 @@ def unified_pd(q_p, k_p, v_p, q_d, k_pages, v_pages, block_tables,
     """One fused P/D attention step.
 
     q_p (Bp,Hq,Sp,D), k_p/v_p (Bp,Hkv,Sp,D)        — prefill batch
-    q_d (Bd,Hq,D), k/v_pages (N,page,Hkv,D),
+    q_d (Bd,Hq,D), k/v_pages (Hkv,N,page,D),
     block_tables (Bd,max_pages), seq_lens (Bd,)     — decode batch
     Returns (o_p (Bp,Hq,Sp,D), o_d (Bd,Hq,D)).
     """
@@ -189,7 +189,7 @@ def unified_pd(q_p, k_p, v_p, q_d, k_pages, v_pages, block_tables,
     Hkv = k_p.shape[1]
     G = Hq // Hkv
     Bd = q_d.shape[0]
-    N, page, _, _ = k_pages.shape
+    _, N, page, _ = k_pages.shape
     max_pages = block_tables.shape[1]
 
     block_q = min(block_q, Sp)
@@ -231,14 +231,14 @@ def unified_pd(q_p, k_p, v_p, q_d, k_pages, v_pages, block_tables,
                                                  clamp(j, nk - 1), 0)),
             pl.BlockSpec((1, 1, G, D),
                          lambda s, j, d, t, ln: (d[s, 5], d[s, 6], 0, 0)),
-            pl.BlockSpec((1, page, 1, D),
+            pl.BlockSpec((1, 1, page, D),
                          lambda s, j, d, t, ln: (
-                             t[d[s, 5], clamp(j, max_pages - 1)], 0,
-                             d[s, 6], 0)),
-            pl.BlockSpec((1, page, 1, D),
+                             d[s, 6], t[d[s, 5], clamp(j, max_pages - 1)],
+                             0, 0)),
+            pl.BlockSpec((1, 1, page, D),
                          lambda s, j, d, t, ln: (
-                             t[d[s, 5], clamp(j, max_pages - 1)], 0,
-                             d[s, 6], 0)),
+                             d[s, 6], t[d[s, 5], clamp(j, max_pages - 1)],
+                             0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D),

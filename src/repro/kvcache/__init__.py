@@ -1,4 +1,4 @@
 from repro.kvcache.manager import (  # noqa: F401
     BlockAllocator, CheckpointStore, KVCacheManager, KVCheckpoint,
-    OutOfBlocks, kv_pages_for, paged_cache_shape,
+    OutOfBlocks, kv_pages_for,
 )

@@ -25,7 +25,7 @@ budget at 0 (or no session ids in the trace) every path below reduces
 exactly to the legacy free/alloc behaviour.
 
 Device-side layout (consumed by kernels/paged_attention.py):
-    k_pages, v_pages : (num_blocks, page_size, kv_heads, head_dim)
+    k_pages, v_pages : (kv_heads, num_blocks, page_size, head_dim)
     block_tables     : (max_requests, max_blocks_per_seq) int32
 """
 from __future__ import annotations
@@ -108,10 +108,6 @@ class CheckpointStore:
 
 def kv_pages_for(num_tokens: int, page_size: int) -> int:
     return -(-num_tokens // page_size)
-
-
-def paged_cache_shape(cfg, num_blocks: int, page_size: int, tp: int = 1):
-    return (num_blocks, page_size, cfg.kv_heads_padded(tp), cfg.head_dim)
 
 
 class BlockAllocator:

@@ -1,9 +1,18 @@
-"""Production meshes.  A FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state — the dry-run sets
-XLA_FLAGS before any jax initialization."""
+"""Meshes.  FUNCTIONS (not module-level constants) so that importing this
+module never touches jax device state — the dry-run sets XLA_FLAGS before
+any jax initialization."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.  The sharding rules
+    (sharding.py) place arrays with ``with_sharding_constraint``, which
+    only accepts Auto axes; JAX's default is Explicit."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,11 +24,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(tp: int = 1):
     """Single-process mesh for CPU examples/tests (1 device)."""
     n = len(jax.devices())
     tp = min(tp, n)
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((n // tp, tp), ("data", "model"))

@@ -249,8 +249,6 @@ def model_flops_for(cfg, shape) -> float:
 def analyze(compiled, cfg, shape, mesh_name: str, chips: int,
             arch: Optional[str] = None) -> RooflineTerms:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):   # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

@@ -42,10 +42,15 @@ as JSON lines (serving/gateway.py + serving/http.py):
     curl -N -X POST http://127.0.0.1:8080/v1/generate \
         -d '{"prompt_len": 512, "max_new_tokens": 64}'
 
-Engine logic is real; step durations come from the calibrated TPU-v5e
-perfmodel (this container has no accelerator — DESIGN.md §6).  Use
-examples/serve_real.py for actual on-CPU token generation with a
-reduced model.
+By default engine logic is real and step durations come from the
+calibrated TPU-v5e perfmodel (``PerfModelExecutor``); no device runs.
+``--executor device`` instead runs every step of each ``rapid`` replica
+on its own TPU chip (``DeviceExecutor``: jitted prefill/decode programs
+over the Pallas kernels, random weights from ``--seed``, greedy
+sampling, measured step times), and refuses to start without a TPU:
+
+    python -m repro.launch.serve --arch starcoder2-3b --mode rapid \
+        --serve http --executor device --max-slots 8 --max-seq-len 2048
 """
 from __future__ import annotations
 
@@ -63,11 +68,27 @@ from repro.serving import (ROUTERS, TRACES, AdmissionPolicy,
 
 
 def _serve_config(mode: str, chips: int, slo: SLOConfig, chunk: int,
-                  max_slots: int) -> ServeConfig:
+                  max_slots: int,
+                  max_seq_len: int = ServeConfig.max_seq_len) -> ServeConfig:
     return ServeConfig(mode=mode, chips=chips, slo=slo,
                        chunk_size=chunk,
                        disagg_split=(chips // 2, chips // 2),
-                       max_batch_slots=max_slots)
+                       max_batch_slots=max_slots, max_seq_len=max_seq_len)
+
+
+def _device_executors(p, args, cfg, serve, modes):
+    """(devices, executor factory) for ``--executor device``: one
+    ``DeviceExecutor`` per replica on ``jax.devices()[i]``."""
+    import jax
+    from repro.core import DeviceExecutor, configure_compile_cache
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        p.error(f"--executor device needs a TPU; JAX's first device is "
+                f"a {devices[0].platform!r} device")
+    if set(modes) != {"rapid"}:
+        p.error("--executor device serves rapid replicas only")
+    configure_compile_cache()
+    return devices, lambda d: DeviceExecutor(cfg, serve, d, seed=args.seed)
 
 
 def run_one(arch: str, mode: str, trace: str, qps: float, duration: float,
@@ -195,10 +216,27 @@ def main(argv=None):
     p.add_argument("--retry-backoff", type=float, default=0.05,
                    help="base seconds of the exponential failover "
                         "backoff (doubles per retry, capped at 2 s)")
+    p.add_argument("--executor", default="perfmodel",
+                   choices=["perfmodel", "device"],
+                   help="'device' runs each replica's steps on its own "
+                        "TPU chip (needs --serve http, --mode rapid); "
+                        "'perfmodel' prices them")
+    p.add_argument("--seed", type=int, default=0,
+                   help="device executor: seed of the random weights and "
+                        "of the prompts' token ids")
+    p.add_argument("--max-slots", type=int, default=128,
+                   help="decode batch slots per replica")
+    p.add_argument("--max-seq-len", type=int,
+                   default=ServeConfig.max_seq_len,
+                   help="--serve http: longest prompt + output of one "
+                        "request (device executor: the length of each KV "
+                        "slot)")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8080)
     p.add_argument("--json", default=None)
     args = p.parse_args(argv)
+    if args.executor == "device" and args.serve != "http":
+        p.error("--executor device serves online: add --serve http")
 
     if args.serve == "http":
         from repro.serving import (Gateway, GatewayPolicy, RealTimeClock,
@@ -211,7 +249,11 @@ def main(argv=None):
         modes = [m if isinstance(m, str) else m.mode for m in mix]
         cfg = get_config(args.arch)
         slo = SLOConfig(itl_ms=args.slo_itl_ms)
-        serve = _serve_config(modes[0], args.chips, slo, args.chunk, 128)
+        device = args.executor == "device"
+        serve = _serve_config(modes[0], 1 if device else args.chips, slo,
+                              args.chunk, args.max_slots, args.max_seq_len)
+        devices, factory = _device_executors(p, args, cfg, serve, modes) \
+            if device else ((), None)
         admission = AdmissionPolicy(
             kv_headroom=args.kv_headroom,
             max_wait_s=args.admission_max_wait,
@@ -224,7 +266,8 @@ def main(argv=None):
                          max_retries=args.max_retries),
                      retry=RetryPolicy(
                          max_retries=args.max_retries,
-                         backoff_base_s=args.retry_backoff))
+                         backoff_base_s=args.retry_backoff),
+                     devices=devices, executor_factory=factory)
         run_http(gw, host=args.host, port=args.port)
         return 0
 
@@ -255,6 +298,7 @@ def main(argv=None):
                           args.qps, args.duration, args.chips,
                           args.slo_itl_ms, args.chunk,
                           admission=admission, rebalance=rebalance,
+                          max_slots=args.max_slots,
                           scale=scale, workload=args.workload,
                           arrival=args.arrival,
                           session_affinity=args.session_affinity)
@@ -293,7 +337,7 @@ def main(argv=None):
         for mode in modes:
             s = run_one(args.arch, mode, args.trace, args.qps,
                         args.duration, args.chips, args.slo_itl_ms,
-                        args.chunk)
+                        args.chunk, max_slots=args.max_slots)
             out[mode] = s
             print(f"{mode:7s} thpt={s['throughput_tok_s']:9.1f} tok/s  "
                   f"goodput={s['goodput_req_s']:6.2f} req/s  "

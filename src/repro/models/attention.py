@@ -1,11 +1,13 @@
 """GQA attention with RoPE / M-RoPE, sliding window, paged/slot KV decode.
 
-Two execution paths:
-  * ``ref``    — pure jnp (chunked, flash-style memory behaviour via
-                 lax.scan over query chunks).  This is the path the
-                 multi-pod dry-run lowers (XLA-native, shardable).
-  * ``pallas`` — the TPU kernels in ``repro.kernels`` (flash_prefill /
-                 paged_attention / unified_pd), validated in interpret mode.
+Three execution paths (``impl``):
+  * ``ref``       — pure jnp (chunked, flash-style memory behaviour via
+                    lax.scan over query chunks).  This is the path the
+                    multi-pod dry-run lowers (XLA-native, shardable).
+  * ``pallas``    — the TPU kernels in ``repro.kernels`` (flash_prefill /
+                    paged_attention), compiled for the chip.
+  * ``interpret`` — the same kernels in Pallas interpret mode: the CPU
+                    tests' stand-in for ``pallas``.
 
 Head-count padding: query heads are padded to a multiple of the TP degree;
 KV heads are padded only when ``cfg.kv_shard_mode(tp) == "heads"`` (cost
@@ -15,6 +17,10 @@ useful-FLOPs ratio) — the logical model is unchanged.
 
 Sliding-window attention stores a ring-buffer cache of ``window`` slots so
 long-context decode reads O(window), not O(S).
+
+The slot cache is laid out ``(KVp, B, S, D)`` per layer: KV head first,
+so it *is* the paged kernel's ``(Hkv, N, page, D)`` pool after a free
+reshape (slot b owns pages [b*S/page, (b+1)*S/page)).
 """
 from __future__ import annotations
 
@@ -131,9 +137,10 @@ def full_attention(params, cfg, x, positions, tp, *, impl: str = "ref",
     """Prefill / train path.  Returns (out, (k, v)) — k/v for cache write."""
     q, k, v = _qkv(params, cfg, x, tp, constrain)
     q, k = _rope(cfg, q, k, positions)
-    if impl == "pallas":
+    if impl in ("pallas", "interpret"):
         from repro.kernels import ops
-        out = ops.flash_prefill(q, k, v, window=cfg.sliding_window)
+        out = ops.flash_prefill(q, k, v, window=cfg.sliding_window,
+                                interpret=impl == "interpret")
     else:
         out = chunked_causal_attention(q, k, v, window=cfg.sliding_window)
     B, S = x.shape[:2]
@@ -148,7 +155,7 @@ def full_attention(params, cfg, x, positions, tp, *, impl: str = "ref",
 
 def cache_shape(cfg, batch: int, max_seq: int, tp: int):
     S = min(max_seq, cfg.sliding_window) if cfg.sliding_window else max_seq
-    return (batch, S, cfg.kv_heads_padded(tp), cfg.head_dim)
+    return (cfg.kv_heads_padded(tp), batch, S, cfg.head_dim)
 
 
 def decode_attention(params, cfg, x, positions, cache_k, cache_v, seq_lens,
@@ -156,34 +163,40 @@ def decode_attention(params, cfg, x, positions, cache_k, cache_v, seq_lens,
     """One-token decode step.
 
     x (B, 1, d); positions (B, 1) or (B, 1, 3); cache_k/v
-    (B, Scache, KVp, D); seq_lens (B,) = tokens already in cache.
+    (KVp, B, Scache, D); seq_lens (B,) = tokens already in cache.
     Returns (out (B,1,d), cache_k, cache_v).
     """
     B = x.shape[0]
     q, k1, v1 = _qkv(params, cfg, x, tp)
     q, k1 = _rope(cfg, q, k1, positions)
-    Scache = cache_k.shape[1]
+    Scache = cache_k.shape[2]
     w = cfg.sliding_window
     slot = (seq_lens % w) if w else seq_lens
     bidx = jnp.arange(B)
-    cache_k = cache_k.at[bidx, slot].set(k1[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[bidx, slot].set(v1[:, 0].astype(cache_v.dtype))
+    # k1/v1 (B,1,KVp,D) -> (KVp,B,D) rows written at each slot's position
+    cache_k = cache_k.at[:, bidx, slot].set(
+        k1[:, 0].transpose(1, 0, 2).astype(cache_k.dtype))
+    cache_v = cache_v.at[:, bidx, slot].set(
+        v1[:, 0].transpose(1, 0, 2).astype(cache_v.dtype))
 
-    if impl == "pallas":
+    if impl in ("pallas", "interpret"):
         from repro.kernels import ops
         out = ops.paged_attention_dense(q[:, 0], cache_k, cache_v,
-                                        seq_lens + 1, window=w)
-        out = out[:, None]
+                                        seq_lens + 1, window=w,
+                                        interpret=impl == "interpret")
     else:
-        scores = _gqa_scores(q, cache_k)  # (B,Hkv,G,1,Scache)
+        Hkv, D = cache_k.shape[0], cache_k.shape[3]
+        qg = q[:, 0].reshape(B, Hkv, -1, D)
+        scores = jnp.einsum("bhgd,hbkd->bhgk", qg, cache_k) / (D ** 0.5)
         kpos = jnp.arange(Scache)
         if w:
             valid = kpos[None, :] < jnp.minimum(seq_lens + 1, w)[:, None]
         else:
             valid = kpos[None, :] <= seq_lens[:, None]
-        scores = jnp.where(valid[:, None, None, None], scores, NEG_INF)
+        scores = jnp.where(valid[:, None, None], scores, NEG_INF)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = _gqa_out(probs.astype(cache_v.dtype), cache_v)
+        out = jnp.einsum("bhgk,hbkd->bhgd", probs.astype(cache_v.dtype),
+                         cache_v)
     out = out.reshape(B, 1, -1)
     return (jnp.einsum("bsh,hd->bsd", out, params["wo"]),
             cache_k, cache_v)
@@ -192,19 +205,22 @@ def decode_attention(params, cfg, x, positions, cache_k, cache_v, seq_lens,
 def prefill_into_cache(cache_k, cache_v, k, v, seq_lens=None, window=None):
     """Write a full prompt's K/V into the slot cache (left-aligned).
 
-    k/v (B, S, KVp, D).  With a ring-buffer (window) cache only the last
-    ``window`` tokens are kept, at their rotated slots.
+    cache_k/v (KVp, B, Sc, D); k/v (B, S, KVp, D).  With a ring-buffer
+    (window) cache only the last ``window`` tokens are kept, at their
+    rotated slots.
     """
-    B, S = k.shape[:2]
+    S = k.shape[1]
+    k = k.transpose(2, 0, 1, 3)          # (KVp, B, S, D)
+    v = v.transpose(2, 0, 1, 3)
     if window:
-        W = cache_k.shape[1]
+        W = cache_k.shape[2]
         take = min(S, W)
         src_pos = jnp.arange(take) + max(S - W, 0)
         slots = src_pos % W
-        cache_k = cache_k.at[:, slots].set(
-            k[:, max(S - W, 0):].astype(cache_k.dtype))
-        cache_v = cache_v.at[:, slots].set(
-            v[:, max(S - W, 0):].astype(cache_v.dtype))
+        cache_k = cache_k.at[:, :, slots].set(
+            k[:, :, max(S - W, 0):].astype(cache_k.dtype))
+        cache_v = cache_v.at[:, :, slots].set(
+            v[:, :, max(S - W, 0):].astype(cache_v.dtype))
     else:
         cache_k = jax.lax.dynamic_update_slice(
             cache_k, k.astype(cache_k.dtype), (0, 0, 0, 0))

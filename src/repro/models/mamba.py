@@ -83,10 +83,11 @@ def mamba_forward(params, cfg, x, *, chunk: int = 256, state=None,
     dt = constrain(dt, ("batch", None, "model"))
     A = -jnp.exp(params["A_log"])  # (din, ds)
 
-    if impl == "pallas":
+    if impl in ("pallas", "interpret"):
         from repro.kernels import ops
         h0 = state["ssm"] if state is not None else None
-        y, h_last = ops.ssm_scan(xs.astype(jnp.float32), dt, A, Bm, Cm, h0=h0)
+        y, h_last = ops.ssm_scan(xs.astype(jnp.float32), dt, A, Bm, Cm,
+                                 h0=h0, interpret=impl == "interpret")
     else:
         y, h_last = ssm_scan_ref(xs.astype(jnp.float32), dt, A, Bm, Cm,
                                  chunk=chunk,

@@ -81,20 +81,23 @@ def init_model(rng: jax.Array, cfg, tp: int = 1):
     leading dim ``cfg.num_periods`` (spec axis None — FSDP shards a dim
     inside the original shape, see sharding.py).
     """
-    import numpy as np
     dtype = jnp.dtype(cfg.dtype)
     b = ParamBuilder(rng, dtype=dtype)
     init_embed(b, cfg)
-    period_params = []
-    period_specs = None
-    for p in range(cfg.num_periods):
-        pb = ParamBuilder(jax.random.fold_in(rng, 1000 + p), dtype=dtype)
+    period_specs = {}
+
+    def one_period(key):
+        pb = ParamBuilder(key, dtype=dtype)
         for pos in range(cfg.period):
             _init_block(pb.scope(f"pos{pos}"), cfg, pos, tp)
-        period_params.append(pb.params)
-        period_specs = pb.specs
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *period_params)
-    b.params["layers"] = stacked
+        period_specs.update(pb.specs)
+        return pb.params
+
+    # vmapped over the periods' keys: one batched op per leaf, so a jitted
+    # init compiles in O(period), not O(num_layers)
+    keys = jnp.stack([jax.random.fold_in(rng, 1000 + p)
+                      for p in range(cfg.num_periods)])
+    b.params["layers"] = jax.vmap(one_period)(keys)
     b.specs["layers"] = jax.tree.map(
         lambda s: (None,) + tuple(s), period_specs,
         is_leaf=lambda s: isinstance(s, tuple))
@@ -183,12 +186,14 @@ def _block_forward(p, cfg, pos, x, positions, tp, impl, constrain,
 def forward(params, cfg, inputs, positions, tp: int = 1, *,
             impl: str = "ref", return_aux: bool = False,
             constrain: Constrain = _IDENTITY, remat: bool = False,
-            last_only: bool = False):
+            last_only: bool = False, lengths=None):
     """Full-sequence forward.  Returns logits (B,S,vocab_padded), or
     (logits, aux) with ``return_aux`` where aux is the per-period stacked
     tree of per-position KV / final state (the serving prefill products).
     ``last_only`` computes the LM head on the final position only (the
-    serving prefill path — full 32K-position logits would be ~100s of GB).
+    serving prefill path — full 32K-position logits would be ~100s of GB);
+    for rows right-padded to a bucket, ``lengths`` (B,) gives each row's
+    valid tokens and the head reads position ``lengths - 1`` instead.
     """
     x = _embed_inputs(params, cfg, inputs, constrain)
 
@@ -213,7 +218,9 @@ def forward(params, cfg, inputs, positions, tp: int = 1, *,
         return x, (auxes if return_aux else None)
 
     x, aux = jax.lax.scan(period_body, x, params["layers"])
-    if last_only:
+    if last_only and lengths is not None:
+        x = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
+    elif last_only:
         x = x[:, -1:]
     logits = lm_logits(params, cfg, x)
     logits = constrain(logits, ("batch", None, "model"))
@@ -320,7 +327,7 @@ def cache_specs(cfg, tp: int = 1):
         if mixer == "attn":
             # "seq" resolves to the data axis for long-context decode
             # (context-parallel KV) and to None otherwise (sharding.py)
-            s = (None, "batch", "seq", kv_spec, None)
+            s = (None, kv_spec, "batch", "seq", None)
             per_pos[f"pos{pos}"] = {"k": s, "v": s}
         elif mixer == "mamba":
             per_pos[f"pos{pos}"] = {
@@ -359,7 +366,7 @@ def write_prefill_to_cache(cfg, cache, aux, seq_len: int):
 
 
 def _write_kv(cache, kv, window):
-    """cache (P,B,Sc,H,D); kv (P,B,S,H,D)."""
+    """cache (P,H,B,Sc,D); kv (P,B,S,H,D)."""
     P = cache.shape[0]
     def one(c, x):
         ck, _ = attn_mod.prefill_into_cache(c, c, x, x, window=window)

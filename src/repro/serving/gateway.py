@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Callable, Dict, List, Optional, Set
+from typing import Callable, Dict, List, Optional, Sequence, Set
 
 from repro.core.events import (CancelledEvent, EventStream, FinishedEvent,
                                PhaseEvent, RejectedEvent, TERMINAL_EVENTS,
@@ -319,7 +319,18 @@ class Gateway:
                  policy: Optional[GatewayPolicy] = None,
                  admission: Optional[AdmissionPolicy] = None,
                  session_affinity: bool = True,
-                 retry: Optional[RetryPolicy] = None):
+                 retry: Optional[RetryPolicy] = None,
+                 devices: Sequence = (),
+                 executor_factory: Optional[Callable] = None):
+        """``executor_factory(device)`` builds the executor of each new
+        worker (initial, replacement or upgrade alike) on
+        ``devices[wid % len(devices)]``; without it workers price their
+        steps with the perfmodel."""
+        if executor_factory is not None and not devices:
+            raise ValueError("executor_factory needs devices to place "
+                             "workers on")
+        self.devices = list(devices)
+        self.executor_factory = executor_factory
         self.cfg = cfg
         self.serve = serve
         self.hw = hw
@@ -359,12 +370,20 @@ class Gateway:
 
     # -- fleet management ---------------------------------------------------
 
-    def add_worker(self, mode: str, serve=None) -> ReplicaWorker:
+    def add_worker(self, mode: str, serve=None,
+                   executor=None) -> ReplicaWorker:
+        """Start a worker.  ``executor`` runs its steps; by default the
+        gateway's ``executor_factory`` builds one on the worker's device,
+        or the perfmodel prices them."""
         from repro.core.engines import make_engine   # break import cycle
         sv = serve if serve is not None else self.serve
         wid = self._next_wid
+        if executor is None and self.executor_factory is not None:
+            executor = self.executor_factory(
+                self.devices[wid % len(self.devices)])
         self._next_wid += 1
-        engine = make_engine(mode, self.cfg, sv, self.hw, loop=self.clock)
+        engine = make_engine(mode, self.cfg, sv, self.hw, loop=self.clock,
+                             executor=executor)
         w = ReplicaWorker(wid, mode, engine, sv, self.clock,
                           sink=self._on_worker_event,
                           heartbeat=self.registry.heartbeat,

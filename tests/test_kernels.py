@@ -58,8 +58,8 @@ def test_flash_prefill(rng, B, Hq, Hkv, S, D, bq, bk, window, dtype):
 def test_paged_attention(rng, B, Hq, Hkv, D, page, max_pages, N, dtype):
     ks = jax.random.split(rng, 3)
     q = _rand(ks[0], (B, Hq, D), dtype)
-    kp = _rand(ks[1], (N, page, Hkv, D), dtype)
-    vp = _rand(ks[2], (N, page, Hkv, D), dtype)
+    kp = _rand(ks[1], (Hkv, N, page, D), dtype)
+    vp = _rand(ks[2], (Hkv, N, page, D), dtype)
     rs = np.random.RandomState(0)
     tabs = jnp.asarray(np.stack(
         [rs.permutation(N)[:max_pages] for _ in range(B)]).astype(np.int32))
@@ -76,8 +76,8 @@ def test_paged_attention_len_one(rng):
     B, Hq, Hkv, D, page, mp, N = 2, 4, 2, 32, 8, 3, 8
     ks = jax.random.split(rng, 3)
     q = _rand(ks[0], (B, Hq, D), jnp.float32)
-    kp = _rand(ks[1], (N, page, Hkv, D), jnp.float32)
-    vp = _rand(ks[2], (N, page, Hkv, D), jnp.float32)
+    kp = _rand(ks[1], (Hkv, N, page, D), jnp.float32)
+    vp = _rand(ks[2], (Hkv, N, page, D), jnp.float32)
     tabs = jnp.tile(jnp.arange(mp, dtype=jnp.int32), (B, 1))
     lens = jnp.array([1, page * mp], jnp.int32)
     out = paged_attention(q, kp, vp, tabs, lens, interpret=True)
@@ -136,8 +136,8 @@ def test_unified_pd(rng, Bp, Bd, Hq, Hkv, Sp, D, page, mp, N, f, win):
     k_p = _rand(ks[1], (Bp, Hkv, Sp, D), jnp.float32)
     v_p = _rand(ks[2], (Bp, Hkv, Sp, D), jnp.float32)
     q_d = _rand(ks[3], (Bd, Hq, D), jnp.float32)
-    kpg = _rand(ks[4], (N, page, Hkv, D), jnp.float32)
-    vpg = _rand(ks[5], (N, page, Hkv, D), jnp.float32)
+    kpg = _rand(ks[4], (Hkv, N, page, D), jnp.float32)
+    vpg = _rand(ks[5], (Hkv, N, page, D), jnp.float32)
     rs = np.random.RandomState(1)
     tabs = jnp.asarray(np.stack(
         [rs.permutation(N)[:mp] for _ in range(Bd)]).astype(np.int32))
@@ -161,8 +161,8 @@ def test_unified_pd_matches_single_kernels(rng):
     k_p = _rand(ks[1], (Bp, Hkv, Sp, D), jnp.float32)
     v_p = _rand(ks[2], (Bp, Hkv, Sp, D), jnp.float32)
     q_d = _rand(ks[3], (Bd, Hq, D), jnp.float32)
-    kpg = _rand(ks[4], (N, page, Hkv, D), jnp.float32)
-    vpg = _rand(ks[5], (N, page, Hkv, D), jnp.float32)
+    kpg = _rand(ks[4], (Hkv, N, page, D), jnp.float32)
+    vpg = _rand(ks[5], (Hkv, N, page, D), jnp.float32)
     tabs = jnp.tile(jnp.arange(mp, dtype=jnp.int32), (Bd, 1))
     lens = jnp.array([5, page * mp], jnp.int32)
     o_p, o_d = unified_pd(q_p, k_p, v_p, q_d, kpg, vpg, tabs, lens,

@@ -133,7 +133,7 @@ def test_sliding_window_ring_cache(rng):
     x, pos = _inputs(cfg, rng, B, S)
     logits, aux = forward(params, cfg, x, pos, 1, return_aux=True)
     cache = init_cache(cfg, B, 64, 1)
-    assert cache["pos0"]["k"].shape[2] == cfg.sliding_window
+    assert cache["pos0"]["k"].shape[3] == cfg.sliding_window
     cache = write_prefill_to_cache(cfg, cache, aux, S)
     nxt = greedy_sample(logits[:, -1:], cfg.vocab_size)
     dl, _ = decode_forward(params, cfg, nxt,

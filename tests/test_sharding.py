@@ -5,13 +5,14 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.config import get_reduced_config
+from repro.launch.mesh import make_mesh
 from repro.sharding import (ShardingRules, make_constrain, param_sharding,
                             rules_for_mesh, spec_to_pspec)
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_translation(mesh):
@@ -24,7 +25,6 @@ def test_spec_translation(mesh):
 
 def test_indivisible_dims_dropped(mesh):
     rules = rules_for_mesh(mesh)
-    big = jax.make_mesh((1, 2), ("data", "model")) if False else mesh
     # shape 3 not divisible by any axis size > 1 -> must drop on 2-wide
     p = spec_to_pspec(("model",), mesh, rules, shape=(3,))
     assert p == P("model") or p == P(None)  # 1-wide mesh: both legal
@@ -40,7 +40,7 @@ def test_param_sharding_tree(mesh):
 
 def test_fsdp_skips_small_and_expert():
     from repro.sharding import _fsdp_spec
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = ShardingRules()
     # small leaf untouched
     assert _fsdp_spec((None,), (64,), mesh, rules) == (None,)

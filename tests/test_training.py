@@ -123,7 +123,8 @@ def test_elastic_restore_changes_placement(rng):
     d = tempfile.mkdtemp()
     try:
         save_checkpoint(d, 1, state)
-        mesh = jax.make_mesh((1,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("data",))
         from jax.sharding import NamedSharding, PartitionSpec as P
         shardings = jax.tree.map(
             lambda _: NamedSharding(mesh, P()), state)
