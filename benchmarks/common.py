@@ -53,8 +53,7 @@ def run_point(arch: str, mode: str, trace: str, qps: float,
     eng.loop.run()
     span = eng.loop.now if eng.loop.now > 0 else 1.0
     out = metrics.summarize(SLOConfig(itl_ms=slo_itl_ms), span)
-    out["kv_util"] = (sum(s.kv_util for s in eng.util_samples) /
-                      max(1, len(eng.util_samples)))
+    out["kv_util"] = eng.kv_util_mean
     return out
 
 

@@ -18,8 +18,7 @@ def main():
         eng = make_engine(mode, cfg, serve_cfg(mode, 100.0))
         eng.enqueue([copy.deepcopy(r) for r in reqs])
         eng.loop.run()
-        kv = (sum(s.kv_util for s in eng.util_samples) /
-              max(1, len(eng.util_samples)))
+        kv = eng.kv_util_mean
         utils[mode] = kv
         rows.append((f"util_{mode}_kv_pool", f"{kv:.3f}",
                      "mean fraction of KV pool live"))

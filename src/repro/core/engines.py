@@ -29,8 +29,10 @@ traces; tests/test_cluster.py single-replica equivalence).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, List, Optional
 
+from repro import obs
 from repro.config import ServeConfig
 from repro.core.events import (EventStream, FinishedEvent, PhaseEvent,
                                RejectedEvent, TokenEvent)
@@ -47,13 +49,6 @@ from repro.kvcache import KVCacheManager, OutOfBlocks, kv_pages_for
 from repro.perfmodel.hw import TPU_V5E, HardwareSpec
 from repro.serving.metrics import RequestRecord
 from repro.serving.sim import EventLoop
-
-
-@dataclasses.dataclass
-class UtilSample:
-    t: float
-    kv_util: float
-    busy: bool
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,7 +164,11 @@ class Engine:
         self.inflight_transfer_tokens = 0
         self.finished: List[Request] = []
         self.rejected: List[Request] = []
-        self.util_samples: List[UtilSample] = []
+        # KV-pool utilization after each decode step, as a running sum
+        self.kv_util_sum = 0.0
+        self.kv_util_samples = 0
+        self.worker = 0              # replica id carried by the spans
+        self._wakes = 0
         self._all: List[Request] = []
         self.stream = EventStream()
 
@@ -202,6 +201,7 @@ class Engine:
         """Admit one request now (the streaming entry point)."""
         sched = self.scheduler
         r.state = sched.arrival_state
+        r.t_queued_ns = time.monotonic_ns()
         self.queues[sched.arrival_queue].append(r)
         self.stream.emit(PhaseEvent(r.rid, self.loop.now, "queued"))
         self._wake(Wake("arrival"))
@@ -226,10 +226,24 @@ class Engine:
                          kv=self.kv, kv_p=self.kv_p, lanes=lanes, wake=wake,
                          max_context=self.executor.max_context)
 
+    @property
+    def kv_util_mean(self) -> float:
+        """Mean KV-pool utilization over the decode steps so far."""
+        return self.kv_util_sum / max(1, self.kv_util_samples)
+
     def _wake(self, wake: Wake) -> None:
-        view = self._view(wake)
-        plan = self.scheduler.schedule(view)
-        self._apply(plan, view)
+        self._wakes += 1
+        with obs.span("rapid.step", worker=self.worker, wake=wake.kind,
+                      step=self._wakes) as sp:
+            view = self._view(wake)
+            with obs.span("rapid.schedule", worker=self.worker) as ss:
+                plan = self.scheduler.schedule(view)
+                ss.set(admits=len(plan.admits),
+                       prefills=len(plan.prefill.batch)
+                       if plan.prefill else 0,
+                       joins=len(plan.decode.joins) if plan.decode else 0)
+            self._apply(plan, view)
+            sp.set(compiles=obs.counter("compiles"))
 
     def _apply(self, plan: StepPlan, view: SchedView) -> None:
         now = self.loop.now
@@ -368,31 +382,36 @@ class Engine:
         now = self.loop.now
         sched = self.scheduler
         freed = False
-        for r in batch:
-            r.t_prefill_end = now
-            # whole-prompt prefill covered every non-cached token;
-            # recording it keeps the conservation invariant
-            # prefill_tokens_done + cached_prefix_len == prompt_len
-            r.prefill_tokens_done = r.prefill_tokens_needed
-            if sched.prefill_route == "transfer":
-                # KV transfer on the critical path (ICI), then decode-side
-                # admission + first-token recompute (vLLM v1, §3.2.1)
-                xfer = self.executor.transfer_seconds(r, self.serve)
-                self.inflight_transfers += 1
-                self.inflight_transfer_tokens += r.prompt_len
-                self.stream.emit(PhaseEvent(r.rid, now, "transfer"))
-                self.loop.after(xfer, lambda r=r: self._transfer_arrived(r))
-            else:
-                r.emit_token(now)             # first token from prefill
-                self.stream.emit(TokenEvent(r.rid, now,
-                                            r.tokens_generated - 1))
-                r.state = State.PREFILL_FINISHED
-                if r.done:                    # single-token request
-                    self._release_kv(r)
-                    self._finish(r)
-                    freed = True
+        joined = sched.prefill_route == "join"
+        with obs.span("rapid.emit", worker=self.worker,
+                      tokens=len(batch) if joined else 0):
+            for r in batch:
+                r.t_prefill_end = now
+                # whole-prompt prefill covered every non-cached token;
+                # recording it keeps the conservation invariant
+                # prefill_tokens_done + cached_prefix_len == prompt_len
+                r.prefill_tokens_done = r.prefill_tokens_needed
+                if not joined:
+                    # KV transfer on the critical path (ICI), then decode-
+                    # side admission + first-token recompute (vLLM v1,
+                    # §3.2.1)
+                    xfer = self.executor.transfer_seconds(r, self.serve)
+                    self.inflight_transfers += 1
+                    self.inflight_transfer_tokens += r.prompt_len
+                    self.stream.emit(PhaseEvent(r.rid, now, "transfer"))
+                    self.loop.after(xfer,
+                                    lambda r=r: self._transfer_arrived(r))
                 else:
-                    self.queues["pending_join"].append(r)
+                    r.emit_token(now)             # first token from prefill
+                    self.stream.emit(TokenEvent(r.rid, now,
+                                                r.tokens_generated - 1))
+                    r.state = State.PREFILL_FINISHED
+                    if r.done:                    # single-token request
+                        self._release_kv(r)
+                        self._finish(r)
+                        freed = True
+                    else:
+                        self.queues["pending_join"].append(r)
         self._lane_busy["prefill"] = False
         self._lane_cost["prefill"] = None
         self.inflight_prefill_tokens = 0
@@ -405,27 +424,32 @@ class Engine:
     def _decode_done(self, batch: List[Request]) -> None:
         now = self.loop.now
         freed = False
-        for r in batch:
-            if r not in self.running:     # preempted mid-loop
-                continue
-            try:
-                self.kv.append_token(r.rid)
-            except OutOfBlocks:
-                victim = self._preempt_victim()
-                if victim is None or victim is r:
+        with obs.span("rapid.emit", worker=self.worker) as sp:
+            emitted = 0
+            for r in batch:
+                if r not in self.running:     # preempted mid-loop
                     continue
-                self.kv.append_token(r.rid)
-            r.emit_token(now)
-            self.running.note_token(r)
-            self.stream.emit(TokenEvent(r.rid, now, r.tokens_generated - 1))
-            if r.done:
-                self._release_kv(r)
-                self.running.remove(r)
-                self._finish(r)
-                freed = True
+                try:
+                    self.kv.append_token(r.rid)
+                except OutOfBlocks:
+                    victim = self._preempt_victim()
+                    if victim is None or victim is r:
+                        continue
+                    self.kv.append_token(r.rid)
+                r.emit_token(now)
+                emitted += 1
+                self.running.note_token(r)
+                self.stream.emit(TokenEvent(r.rid, now,
+                                            r.tokens_generated - 1))
+                if r.done:
+                    self._release_kv(r)
+                    self.running.remove(r)
+                    self._finish(r)
+                    freed = True
+            sp.set(tokens=emitted)
         self._lane_busy["decode"] = False
         self._lane_cost["decode"] = None
-        self.util_samples.append(UtilSample(now, self.kv.utilization, True))
+        self._note_kv_util()
         self._wake(Wake("decode_done", kv_freed=freed))
 
     def _step_done(self, decode_batch: List[Request],
@@ -467,8 +491,12 @@ class Engine:
                 self._finish(r)
         self._lane_busy["step"] = False
         self._lane_cost["step"] = None
-        self.util_samples.append(UtilSample(now, self.kv.utilization, True))
+        self._note_kv_util()
         self._wake(Wake("step_done"))
+
+    def _note_kv_util(self) -> None:
+        self.kv_util_sum += self.kv.utilization
+        self.kv_util_samples += 1
 
     # -- terminal transitions ------------------------------------------------
     def _release_kv(self, r: Request) -> None:
@@ -533,6 +561,7 @@ class Engine:
         # recompute-on-resume: the whole context becomes the new "prompt"
         sched = self.scheduler
         victim.state = sched.requeue_state
+        victim.t_queued_ns = time.monotonic_ns()
         self.queues[sched.requeue_queue].appendleft(victim)
 
     # -- targeted removal / crash halt (serving gateway) --------------------

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.core.scheduler import SchedView, StepPlan
 from repro.perfmodel import batch as B
 from repro.perfmodel import costs as C
@@ -62,9 +63,11 @@ class Executor:
     ``max_context`` bounds prompt + output of one request (None: only
     the KV pool bounds it); ``pool_blocks`` sizes the engine's decode KV
     pool from what the executor holds (None: the scheduler's HBM model).
+    ``worker`` is the replica id its spans carry.
     """
 
     max_context: Optional[int] = None
+    worker: int = 0
 
     def pool_blocks(self) -> Optional[int]:
         return None
@@ -350,6 +353,14 @@ class DeviceExecutor(Executor):
     greedy.  Every step blocks until the device is done and reports the
     wall time since ``execute`` was called.
 
+    Spans (``repro.obs``): ``rapid.execute`` around each call that
+    launches work, ``rapid.queue`` from a request's engine queue entry to
+    its ``rapid.prefill``, ``rapid.insert`` per slot insert,
+    ``rapid.decode`` per decode step, and inside prefill and decode
+    ``rapid.put`` (inputs to the device) and ``rapid.sync`` (the wait
+    and the read-back of the next tokens).  Counters: ``steps``, valid
+    and padded prefill tokens, live and free decode slot-steps.
+
     Prompts are token ids drawn from ``(seed, rid)`` (``prompt_ids``);
     ``token_ids(rid)`` returns prompt plus generated ids.  With
     ``record_logits`` the served logits are kept by input position:
@@ -394,6 +405,7 @@ class DeviceExecutor(Executor):
         self._lens = np.zeros(B, np.int32)
         self._ids: Dict[int, List[int]] = {}
         self._pending: Dict[int, tuple] = {}     # rid -> (kv, length)
+        self._calls = 0
 
         t0 = time.perf_counter()
         on_device = SingleDeviceSharding(device)
@@ -433,19 +445,24 @@ class DeviceExecutor(Executor):
             raise ValueError("DeviceExecutor runs the rapid scheduler "
                              "only (no hybrid or split-pool steps)")
         t0 = time.perf_counter()
+        self._calls += 1
+        if plan.prefill is None and plan.decode is None:
+            return StepOutputs()
+        obs.count("steps")
         p_out = d_out = None
-        if plan.prefill is not None:
-            # the previous prefill has completed: every prompt KV still
-            # held is for a request in pending_join, or it is dead
-            live = {r.rid for r in view.queues["pending_join"]}
-            for rid in [k for k in self._pending if k not in live]:
-                del self._pending[rid]
-            for r in plan.prefill.batch:
-                self._prefill(r)
-            p_out = LaunchOutcome(time.perf_counter() - t0, C.ZERO_COST)
-        if plan.decode is not None:
-            self._decode(list(view.running) + list(plan.decode.joins))
-            d_out = LaunchOutcome(time.perf_counter() - t0, C.ZERO_COST)
+        with obs.span("rapid.execute", worker=self.worker, step=self._calls):
+            if plan.prefill is not None:
+                # the previous prefill has completed: every prompt KV
+                # still held is for a request in pending_join, or dead
+                live = {r.rid for r in view.queues["pending_join"]}
+                for rid in [k for k in self._pending if k not in live]:
+                    del self._pending[rid]
+                for r in plan.prefill.batch:
+                    self._prefill(r)
+                p_out = LaunchOutcome(time.perf_counter() - t0, C.ZERO_COST)
+            if plan.decode is not None:
+                self._decode(list(view.running) + list(plan.decode.joins))
+                d_out = LaunchOutcome(time.perf_counter() - t0, C.ZERO_COST)
         return StepOutputs(prefill=p_out, decode=d_out)
 
     # -- steps ---------------------------------------------------------------
@@ -472,13 +489,25 @@ class DeviceExecutor(Executor):
                                f"needed, {len(ids)} known")
         del ids[n:]
         Lb = self._bucket(n)
-        toks = np.zeros((1, Lb), np.int32)
-        toks[0, :n] = ids
-        tok, logits, kv = _prefill_step(
-            self.params, self._put(toks), self._put(np.array([n], np.int32)),
-            cfg=self.cfg, impl=self.impl)
-        jax.block_until_ready((tok, kv))
-        ids.append(int(tok[0]))
+        obs.count("prefill_tokens_valid", n)
+        obs.count("prefill_tokens_padded", Lb - n)
+        w = self.worker
+        with obs.span("rapid.prefill", worker=w, rid=r.rid, valid=n,
+                      bucket=Lb) as sp:
+            if r.t_queued_ns is not None:
+                obs.record("rapid.queue", r.t_queued_ns, sp.start, worker=w,
+                           rid=r.rid, preempted=int(r.preemptions > 0))
+            with obs.span("rapid.put", worker=w):
+                toks = np.zeros((1, Lb), np.int32)
+                toks[0, :n] = ids
+                toks = self._put(toks)
+                length = self._put(np.array([n], np.int32))
+            tok, logits, kv = _prefill_step(self.params, toks, length,
+                                            cfg=self.cfg, impl=self.impl)
+            with obs.span("rapid.sync", worker=w):
+                jax.block_until_ready((tok, kv))
+                # read on the host: indexing the device array would compile
+                ids.append(int(np.asarray(tok)[0]))
         self._pending[r.rid] = (kv, n)
         if self.logits is not None:
             self.logits.setdefault(r.rid, {})[n - 1] = \
@@ -501,19 +530,32 @@ class DeviceExecutor(Executor):
                 raise RuntimeError("decode batch exceeds max_batch_slots")
             i = self._row_rid.index(None)
             kv, n = self._pending.pop(r.rid)
-            self.cache = _insert_slot(self.cache, kv, self._put(np.int32(i)))
+            with obs.span("rapid.insert", worker=self.worker, rid=r.rid,
+                          slot=i):
+                self.cache = _insert_slot(self.cache, kv,
+                                          self._put(np.int32(i)))
             self._row_rid[i] = r.rid
             self._lens[i] = n
             self._tokens[i] = self._ids[r.rid][-1]
         if self._lens.max() >= self.max_context:
             raise RuntimeError("a decode step would write past its slot")
-        nxt, logits, self.cache = _decode_step(
-            self.params, self.cache, self._put(self._tokens),
-            self._put(self._lens), cfg=self.cfg, impl=self.impl)
-        jax.block_until_ready((nxt, self.cache))
-        nxt = np.asarray(nxt)
-        rec = np.asarray(logits, np.float32) \
-            if self.logits is not None else None
+        slots = len(self._row_rid)
+        live = slots - self._row_rid.count(None)
+        obs.count("decode_slot_steps_live", live)
+        obs.count("decode_slot_steps_free", slots - live)
+        w = self.worker
+        with obs.span("rapid.decode", worker=w, live=live, slots=slots):
+            with obs.span("rapid.put", worker=w):
+                tokens = self._put(self._tokens)
+                lens = self._put(self._lens)
+            nxt, logits, self.cache = _decode_step(
+                self.params, self.cache, tokens, lens, cfg=self.cfg,
+                impl=self.impl)
+            with obs.span("rapid.sync", worker=w):
+                jax.block_until_ready((nxt, self.cache))
+                nxt = np.asarray(nxt)
+                rec = np.asarray(logits, np.float32) \
+                    if self.logits is not None else None
         for i, rid in enumerate(self._row_rid):
             if rid is None:
                 continue

@@ -75,6 +75,9 @@ class Request:
     # admission capped max_new_tokens so prompt+output fits a colocated
     # pool (production-shaped truncation instead of a decode stall)
     truncated: bool = False
+    # time.monotonic_ns() when the request last entered its engine's
+    # queue (submit, or requeue after preemption)
+    t_queued_ns: Optional[int] = None
 
     @property
     def ttft(self) -> Optional[float]:

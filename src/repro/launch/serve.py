@@ -51,6 +51,11 @@ sampling, measured step times), and refuses to start without a TPU:
 
     python -m repro.launch.serve --arch starcoder2-3b --mode rapid \
         --serve http --executor device --max-slots 8 --max-seq-len 2048
+
+``--profiler-port N`` (with ``--serve http``) also starts JAX's profiler
+server on port N, so a live trace can be captured from outside with the
+program's ``rapid.*`` spans beside the device ops (README, "Tracing a
+live server"); ``GET /metrics`` carries the span recorder's counters.
 """
 from __future__ import annotations
 
@@ -233,10 +238,15 @@ def main(argv=None):
                         "slot)")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8080)
+    p.add_argument("--profiler-port", type=int, default=None,
+                   help="--serve http: start jax.profiler's server on "
+                        "this port for live trace capture")
     p.add_argument("--json", default=None)
     args = p.parse_args(argv)
     if args.executor == "device" and args.serve != "http":
         p.error("--executor device serves online: add --serve http")
+    if args.profiler_port is not None and args.serve != "http":
+        p.error("--profiler-port profiles a live server: add --serve http")
 
     if args.serve == "http":
         from repro.serving import (Gateway, GatewayPolicy, RealTimeClock,
@@ -268,6 +278,10 @@ def main(argv=None):
                          max_retries=args.max_retries,
                          backoff_base_s=args.retry_backoff),
                      devices=devices, executor_factory=factory)
+        if args.profiler_port is not None:
+            import jax
+            jax.profiler.start_server(args.profiler_port)
+            print(f"profiler server on port {args.profiler_port}")
         run_http(gw, host=args.host, port=args.port)
         return 0
 

@@ -560,6 +560,7 @@ class Cluster:
         rep = Replica(idx=len(self.replicas), mode=spec.mode,
                       engine=engine, serve=serve,
                       assigned=IndexedQueue(serve.page_size))
+        engine.worker = engine.executor.worker = rep.idx
         rep.engine.subscribe(self.stream.emit)   # forward into fleet stream
         self.replicas.append(rep)
         return rep

@@ -44,6 +44,7 @@ import collections
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence, Set
 
+from repro import obs
 from repro.core.events import (CancelledEvent, EventStream, FinishedEvent,
                                PhaseEvent, RejectedEvent, TERMINAL_EVENTS,
                                TokenEvent)
@@ -384,6 +385,7 @@ class Gateway:
         self._next_wid += 1
         engine = make_engine(mode, self.cfg, sv, self.hw, loop=self.clock,
                              executor=executor)
+        engine.worker = engine.executor.worker = wid
         w = ReplicaWorker(wid, mode, engine, sv, self.clock,
                           sink=self._on_worker_event,
                           heartbeat=self.registry.heartbeat,
@@ -436,7 +438,10 @@ class Gateway:
             # passed with nothing to do
             self._idle = False
             self.registry.resume_ticks()
-        self._admit(st)
+        with obs.span("rapid.submit", rid=r.rid) as sp:
+            self._admit(st)
+            # the worker it went to; -1 while admission holds it
+            sp.set(worker=st.worker.wid if st.worker is not None else -1)
         return ch
 
     def _admit(self, st: _RequestState) -> None:
@@ -899,4 +904,5 @@ class Gateway:
         summary["fleet"]["cancelled"] = self.cancellations
         summary["fleet"]["fenced_beats"] = self.registry.fenced_beats
         summary["admission"] = dict(self.admission.stats)
+        summary["counters"] = obs.counters()
         return summary

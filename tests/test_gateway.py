@@ -269,6 +269,9 @@ def test_http_generate_healthz_metrics():
         m = _json.loads(payload)
         assert m["fleet"]["completed"] == 1
         assert "loop" in m["fleet"]
+        # the span recorder's counters (repro.obs) ride along
+        assert {"steps", "compiles", "prefill_tokens_valid",
+                "decode_slot_steps_live"} <= set(m["counters"])
 
         status, _ = await call("GET", "/nope")
         assert status == 404
