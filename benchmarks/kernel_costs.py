@@ -40,8 +40,8 @@ def main():
                  f"tpu_v5e_roofline_us={est:.1f}"))
     # paged attention decode
     N, page, mp, Bd = 64, 16, 16, 8
-    kp = jax.random.normal(ks[0], (Hkv, N, page, D), jnp.float32)
-    vp = jax.random.normal(ks[1], (Hkv, N, page, D), jnp.float32)
+    kp = jax.random.normal(ks[0], (Hkv, N, D, page), jnp.float32)
+    vp = jax.random.normal(ks[1], (Hkv, N, D, page), jnp.float32)
     qd = jax.random.normal(ks[2], (Bd, Hq, D), jnp.float32)
     tabs = jnp.tile(jnp.arange(mp, dtype=jnp.int32), (Bd, 1))
     lens = jnp.full((Bd,), mp * page, jnp.int32)
@@ -52,7 +52,8 @@ def main():
                  f"tpu_v5e_bw_bound_us={est:.2f}"))
     # unified P/D
     us = _t(unified_pd, q.transpose(0, 2, 1, 3)[:, :, :, :]
-            if False else q, k, v, qd, kp, vp, tabs, lens,
+            if False else q, k, v, qd, kp.swapaxes(2, 3),
+            vp.swapaxes(2, 3), tabs, lens,
             f_decode=0.5, block_q=128, block_k=128, interpret=True, n=1)
     rows.append(("kernel_unified_pd_us", f"{us:.0f}",
                  "fused P+D single launch"))

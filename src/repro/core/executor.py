@@ -319,8 +319,8 @@ def _prefill_step(params, tokens, length, *, cfg, impl):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _insert_slot(cache, kv, row):
-    """Write a one-slot cache (leaves (P, H, 1, Lb, D)) into slot ``row``
-    of the slot cache (leaves (P, H, B, Sc, D)) in place."""
+    """Write a one-slot cache (leaves (P, H, 1, D, Lb)) into slot ``row``
+    of the slot cache (leaves (P, H, B, D, Sc)) in place."""
     return jax.tree.map(
         lambda c, x: jax.lax.dynamic_update_slice(
             c, x.astype(c.dtype), (0, 0, row, 0, 0)), cache, kv)

@@ -18,8 +18,8 @@ from repro.kernels import paged_attention as _pa
 from repro.kernels import ssm_scan as _ssm
 from repro.kernels import unified_pd as _updk
 
-# Pages of a dense slot cache: a multiple of 16 rows (a bf16 sublane
-# tile), at most this many tokens.
+# Pages of a dense slot cache: this many tokens on the lanes, where the
+# slot length is a multiple of it.
 DENSE_PAGE = 128
 
 
@@ -40,42 +40,49 @@ def flash_prefill(q, k, v, *, window: Optional[int] = None,
 
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                     interpret: bool = False):
-    """q (B,Hq,D) over paged cache (Hkv,N,page,D) -> (B,Hq,D)."""
+    """q (B,Hq,D) over paged cache (Hkv,N,D,page) -> (B,Hq,D)."""
     return _pa.paged_attention(q, k_pages, v_pages, block_tables,
                                seq_lens, interpret=interpret)
 
 
 def dense_page_size(seq: int, page: int = DENSE_PAGE) -> int:
-    """Largest multiple of 16 that is <= ``page`` and divides ``seq``."""
-    for p in range(min(page, seq) // 16 * 16, 0, -16):
-        if seq % p == 0:
-            return p
-    raise ValueError(
-        f"a dense cache of {seq} slots cannot be paged: no multiple of 16 "
-        f"up to {page} divides it (size max_seq_len to a multiple of 16)")
+    """``page`` where it divides ``seq``, else the whole slot: a page is
+    the lane dim of the kernel's block, so a multiple of 128 or the row."""
+    return page if seq % page == 0 else seq
 
 
-def paged_attention_dense(q, cache_k, cache_v, seq_lens, *,
+def paged_attention_dense(q, cache_k, cache_v, layer, seq_lens, *,
                           window: Optional[int] = None,
                           page: int = DENSE_PAGE, interpret: bool = False):
-    """Decode attention over a *dense slot* cache via the paged kernel.
+    """Decode attention over layer ``layer`` of a *dense slot* cache via
+    the paged kernel.
 
-    q (B,Hq,D); cache_k/v (Hkv,B,Sc,D); seq_lens (B,) valid tokens
-    (for ring-buffer windows pass min(len, window) — all slots valid).
-    The dense cache is viewed as trivially-paged without a copy: slot b
-    owns pages [b*np, (b+1)*np), identity block table.
+    q (B,Hq,D); cache_k/v (P,Hkv,B,D,Sc), stacked over P layers; seq_lens
+    (B,) valid tokens (for ring-buffer windows pass min(len, window) —
+    all slots valid).  The kernel reads the stacked cache where it lies:
+    slot b is row b, its pages [b*np, (b+1)*np) in an identity block
+    table, and ``layer`` picks the layer.
     """
-    Hkv, B, Sc, D = cache_k.shape
+    B, Sc = cache_k.shape[2], cache_k.shape[4]
     page = dense_page_size(Sc, page)
     n_pages = Sc // page
-    kp = cache_k.reshape(Hkv, B * n_pages, page, D)
-    vp = cache_v.reshape(Hkv, B * n_pages, page, D)
     tables = (jnp.arange(B)[:, None] * n_pages +
               jnp.arange(n_pages)[None, :]).astype(jnp.int32)
     lens = seq_lens.astype(jnp.int32)
     if window is not None:
         lens = jnp.minimum(lens, window)
-    return _pa.paged_attention(q, kp, vp, tables, lens, interpret=interpret)
+    return _pa.paged_attention(q, cache_k, cache_v, tables, lens, layer,
+                               page=page, interpret=interpret)
+
+
+def append_dense(cache_k, cache_v, k_new, v_new, layer, slot, *,
+                 interpret: bool = False):
+    """Write each slot's new K/V column k/v_new (B,Hkv,D) at token
+    ``slot[b]`` of layer ``layer`` of a dense slot cache (P,Hkv,B,D,Sc),
+    in place (``paged_attention.kv_append``)."""
+    page = dense_page_size(cache_k.shape[4])
+    return _pa.kv_append(cache_k, cache_v, k_new, v_new, slot, layer,
+                         page=page, interpret=interpret)
 
 
 def ssm_scan(xs, dt, A, Bm, Cm, *, h0=None, chunk: int = 128,
@@ -95,7 +102,8 @@ def unified_pd(q_p, k_p, v_p, q_d, k_pages, v_pages, block_tables,
                window: Optional[int] = None, block_q: int = 512,
                block_k: int = 512, interpret: bool = False):
     """Fused concurrent P/D attention step (layouts as models produce):
-    q_p/k_p/v_p (Bp,S,H,D); q_d (Bd,Hq,D); pages (Hkv,N,page,D).
+    q_p/k_p/v_p (Bp,S,H,D); q_d (Bd,Hq,D); pages (Hkv,N,page,D) — the
+    fused kernel keeps the token-major page layout.
     Returns (o_p (Bp,S,Hq,D), o_d (Bd,Hq,D))."""
     Sp = q_p.shape[1]
     bq = min(block_q, max(8, Sp))

@@ -42,26 +42,29 @@ def causal_attention(q, k, v, *, window: Optional[int] = None,
 def paged_attention(q, k_pages, v_pages, block_tables, seq_lens):
     """Decode attention over a paged KV cache.
 
-    q (B,Hq,D); k/v_pages (Hkv, N, page, D); block_tables (B, max_pages)
-    int32; seq_lens (B,) = valid tokens per sequence (including the
-    current token, already written to its slot).  Returns (B,Hq,D).
+    q (B,Hq,D); k/v_pages (Hkv, N, D, page), tokens on the last axis;
+    block_tables (B, max_pages) int32; seq_lens (B,) = valid tokens per
+    sequence (including the current token, already written to its slot).
+    Returns (B,Hq,D).
     """
     B, Hq, D = q.shape
-    Hkv, N, page, _ = k_pages.shape
+    Hkv, N, _, page = k_pages.shape
     G = Hq // Hkv
     max_pages = block_tables.shape[1]
 
     def one(qb, tab, n):
-        # gather this sequence's pages -> (Hkv, max_pages*page, D)
-        kk = k_pages[:, tab].reshape(Hkv, max_pages * page, D)
-        vv = v_pages[:, tab].reshape(Hkv, max_pages * page, D)
+        # gather this sequence's pages -> (Hkv, D, max_pages*page)
+        kk = k_pages[:, tab].transpose(0, 2, 1, 3).reshape(
+            Hkv, D, max_pages * page)
+        vv = v_pages[:, tab].transpose(0, 2, 1, 3).reshape(
+            Hkv, D, max_pages * page)
         qg = qb.reshape(Hkv, G, D).astype(jnp.float32)
-        scores = jnp.einsum("hgd,hkd->hgk", qg,
+        scores = jnp.einsum("hgd,hdk->hgk", qg,
                             kk.astype(jnp.float32)) / (D ** 0.5)
         valid = jnp.arange(max_pages * page) < n
         scores = jnp.where(valid[None, None], scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("hgk,hkd->hgd", probs, vv.astype(jnp.float32))
+        out = jnp.einsum("hgk,hdk->hgd", probs, vv.astype(jnp.float32))
         return out.reshape(Hq, D)
 
     return jax.vmap(one)(q, block_tables, seq_lens).astype(q.dtype)
@@ -95,7 +98,9 @@ def ssm_scan(xs, dt, A, Bm, Cm, h0=None):
 def unified_pd(q_p, k_p, v_p, q_d, k_pages, v_pages, block_tables,
                seq_lens, *, window: Optional[int] = None):
     """Oracle for the unified P/D step: prefill flash output + decode
-    paged output, computed independently (they share no data)."""
+    paged output, computed independently (they share no data).  Its
+    pages are (Hkv, N, page, D), token-major."""
     o_p = causal_attention(q_p, k_p, v_p, window=window)
-    o_d = paged_attention(q_d, k_pages, v_pages, block_tables, seq_lens)
+    o_d = paged_attention(q_d, k_pages.swapaxes(2, 3),
+                          v_pages.swapaxes(2, 3), block_tables, seq_lens)
     return o_p, o_d

@@ -25,7 +25,7 @@ budget at 0 (or no session ids in the trace) every path below reduces
 exactly to the legacy free/alloc behaviour.
 
 Device-side layout (consumed by kernels/paged_attention.py):
-    k_pages, v_pages : (kv_heads, num_blocks, page_size, head_dim)
+    k_pages, v_pages : (kv_heads, num_blocks, head_dim, page_size)
     block_tables     : (max_requests, max_blocks_per_seq) int32
 """
 from __future__ import annotations

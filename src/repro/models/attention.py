@@ -18,9 +18,11 @@ useful-FLOPs ratio) — the logical model is unchanged.
 Sliding-window attention stores a ring-buffer cache of ``window`` slots so
 long-context decode reads O(window), not O(S).
 
-The slot cache is laid out ``(KVp, B, S, D)`` per layer: KV head first,
-so it *is* the paged kernel's ``(Hkv, N, page, D)`` pool after a free
-reshape (slot b owns pages [b*S/page, (b+1)*S/page)).
+The slot cache is laid out ``(KVp, B, D, S)`` per layer, tokens on the
+last (lane) axis, and the model stacks it over layers: ``(P, KVp, B, D,
+S)`` *is* the paged kernel's pool as it stands (slot b is row b, holding
+pages [b*S/page, (b+1)*S/page)).  Decode writes each new column and
+reads its layer in that one buffer, in place.
 """
 from __future__ import annotations
 
@@ -155,39 +157,47 @@ def full_attention(params, cfg, x, positions, tp, *, impl: str = "ref",
 
 def cache_shape(cfg, batch: int, max_seq: int, tp: int):
     S = min(max_seq, cfg.sliding_window) if cfg.sliding_window else max_seq
-    return (cfg.kv_heads_padded(tp), batch, S, cfg.head_dim)
+    return (cfg.kv_heads_padded(tp), batch, cfg.head_dim, S)
 
 
-def decode_attention(params, cfg, x, positions, cache_k, cache_v, seq_lens,
-                     tp, *, impl: str = "ref"):
-    """One-token decode step.
+def decode_attention(params, cfg, x, positions, cache_k, cache_v, layer,
+                     seq_lens, tp, *, impl: str = "ref"):
+    """One-token decode step on layer ``layer`` of the stacked cache.
 
     x (B, 1, d); positions (B, 1) or (B, 1, 3); cache_k/v
-    (KVp, B, Scache, D); seq_lens (B,) = tokens already in cache.
+    (P, KVp, B, D, Scache), every layer's; layer: scalar int32; seq_lens
+    (B,) = tokens already in cache.  Each slot's new K/V column is
+    written at ``(layer, :, b, :, seq_lens[b])`` (``kv_append``, which
+    rewrites only the page that holds it) and the kernel reads the layer
+    where it lies, so the cache is neither sliced nor copied.
     Returns (out (B,1,d), cache_k, cache_v).
     """
     B = x.shape[0]
     q, k1, v1 = _qkv(params, cfg, x, tp)
     q, k1 = _rope(cfg, q, k1, positions)
-    Scache = cache_k.shape[2]
+    Scache = cache_k.shape[4]
     w = cfg.sliding_window
     slot = (seq_lens % w) if w else seq_lens
-    bidx = jnp.arange(B)
-    # k1/v1 (B,1,KVp,D) -> (KVp,B,D) rows written at each slot's position
-    cache_k = cache_k.at[:, bidx, slot].set(
-        k1[:, 0].transpose(1, 0, 2).astype(cache_k.dtype))
-    cache_v = cache_v.at[:, bidx, slot].set(
-        v1[:, 0].transpose(1, 0, 2).astype(cache_v.dtype))
-
     if impl in ("pallas", "interpret"):
         from repro.kernels import ops
-        out = ops.paged_attention_dense(q[:, 0], cache_k, cache_v,
+        interpret = impl == "interpret"
+        cache_k, cache_v = ops.append_dense(
+            cache_k, cache_v, k1[:, 0], v1[:, 0], layer, slot,
+            interpret=interpret)
+        out = ops.paged_attention_dense(q[:, 0], cache_k, cache_v, layer,
                                         seq_lens + 1, window=w,
-                                        interpret=impl == "interpret")
+                                        interpret=interpret)
     else:
-        Hkv, D = cache_k.shape[0], cache_k.shape[3]
+        bidx = jnp.arange(B)
+        cache_k = cache_k.at[layer, :, bidx, :, slot].set(
+            k1[:, 0].astype(cache_k.dtype))
+        cache_v = cache_v.at[layer, :, bidx, :, slot].set(
+            v1[:, 0].astype(cache_v.dtype))
+        ck = jax.lax.dynamic_index_in_dim(cache_k, layer, 0, keepdims=False)
+        cv = jax.lax.dynamic_index_in_dim(cache_v, layer, 0, keepdims=False)
+        Hkv, D = ck.shape[0], ck.shape[2]
         qg = q[:, 0].reshape(B, Hkv, -1, D)
-        scores = jnp.einsum("bhgd,hbkd->bhgk", qg, cache_k) / (D ** 0.5)
+        scores = jnp.einsum("bhgd,hbdk->bhgk", qg, ck) / (D ** 0.5)
         kpos = jnp.arange(Scache)
         if w:
             valid = kpos[None, :] < jnp.minimum(seq_lens + 1, w)[:, None]
@@ -195,8 +205,7 @@ def decode_attention(params, cfg, x, positions, cache_k, cache_v, seq_lens,
             valid = kpos[None, :] <= seq_lens[:, None]
         scores = jnp.where(valid[:, None, None], scores, NEG_INF)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = jnp.einsum("bhgk,hbkd->bhgd", probs.astype(cache_v.dtype),
-                         cache_v)
+        out = jnp.einsum("bhgk,hbdk->bhgd", probs.astype(cv.dtype), cv)
     out = out.reshape(B, 1, -1)
     return (jnp.einsum("bsh,hd->bsd", out, params["wo"]),
             cache_k, cache_v)
@@ -205,22 +214,22 @@ def decode_attention(params, cfg, x, positions, cache_k, cache_v, seq_lens,
 def prefill_into_cache(cache_k, cache_v, k, v, seq_lens=None, window=None):
     """Write a full prompt's K/V into the slot cache (left-aligned).
 
-    cache_k/v (KVp, B, Sc, D); k/v (B, S, KVp, D).  With a ring-buffer
+    cache_k/v (KVp, B, D, Sc); k/v (B, S, KVp, D).  With a ring-buffer
     (window) cache only the last ``window`` tokens are kept, at their
     rotated slots.
     """
     S = k.shape[1]
-    k = k.transpose(2, 0, 1, 3)          # (KVp, B, S, D)
-    v = v.transpose(2, 0, 1, 3)
+    k = k.transpose(2, 0, 3, 1)          # (KVp, B, D, S)
+    v = v.transpose(2, 0, 3, 1)
     if window:
-        W = cache_k.shape[2]
+        W = cache_k.shape[3]
         take = min(S, W)
         src_pos = jnp.arange(take) + max(S - W, 0)
         slots = src_pos % W
-        cache_k = cache_k.at[:, :, slots].set(
-            k[:, :, max(S - W, 0):].astype(cache_k.dtype))
-        cache_v = cache_v.at[:, :, slots].set(
-            v[:, :, max(S - W, 0):].astype(cache_v.dtype))
+        cache_k = cache_k.at[..., slots].set(
+            k[..., max(S - W, 0):].astype(cache_k.dtype))
+        cache_v = cache_v.at[..., slots].set(
+            v[..., max(S - W, 0):].astype(cache_v.dtype))
     else:
         cache_k = jax.lax.dynamic_update_slice(
             cache_k, k.astype(cache_k.dtype), (0, 0, 0, 0))

@@ -327,7 +327,7 @@ def cache_specs(cfg, tp: int = 1):
         if mixer == "attn":
             # "seq" resolves to the data axis for long-context decode
             # (context-parallel KV) and to None otherwise (sharding.py)
-            s = (None, kv_spec, "batch", "seq", None)
+            s = (None, kv_spec, "batch", None, "seq")
             per_pos[f"pos{pos}"] = {"k": s, "v": s}
         elif mixer == "mamba":
             per_pos[f"pos{pos}"] = {
@@ -366,7 +366,7 @@ def write_prefill_to_cache(cfg, cache, aux, seq_len: int):
 
 
 def _write_kv(cache, kv, window):
-    """cache (P,H,B,Sc,D); kv (P,B,S,H,D)."""
+    """cache (P,H,B,D,Sc); kv (P,B,S,H,D)."""
     P = cache.shape[0]
     def one(c, x):
         ck, _ = attn_mod.prefill_into_cache(c, c, x, x, window=window)
@@ -387,33 +387,34 @@ def decode_forward(params, cfg, inputs, positions, cache, seq_lens,
     # is dynamic-update-sliced in place aliases to a single buffer, where
     # an xs->ys cache would double-buffer ~5 GB/chip at decode_32k scale
     # (measured in the dry-run; see EXPERIMENTS.md §Dry-run notes).
+    # Attention layers write and read their K/V in that buffer at the
+    # layer's index; the small recurrent states are sliced out and back.
     def period_body(carry, scanned):
         x, cache = carry
         layer_p, idx = scanned
         layer_p, x = grad_barrier((layer_p, x))
-        new_c = {}
-        layer_c = jax.tree.map(
-            lambda t: jax.lax.dynamic_index_in_dim(t, idx, 0,
-                                                   keepdims=False), cache)
+        cache = dict(cache)
         for pos in range(cfg.period):
-            p = layer_p[f"pos{pos}"]
-            c = layer_c[f"pos{pos}"]
+            key = f"pos{pos}"
+            p = layer_p[key]
             mixer = cfg.mixer_at(pos)
             h = rmsnorm(x, p["norm1"]["w"], cfg.norm_eps)
             if mixer == "attn":
                 out, ck, cv = attn_mod.decode_attention(
-                    p["mixer"], cfg, h, positions, c["k"], c["v"],
-                    seq_lens, tp, impl=impl)
-                new_c[f"pos{pos}"] = {"k": ck, "v": cv}
-            elif mixer == "mamba":
-                out, st = mamba_mod.mamba_decode_step(p["mixer"], cfg, h, c)
-                new_c[f"pos{pos}"] = st
-            elif mixer == "mlstm":
-                out, st = xlstm_mod.mlstm_decode_step(p["mixer"], cfg, h, c)
-                new_c[f"pos{pos}"] = st
-            elif mixer == "slstm":
-                out, st = xlstm_mod.slstm_decode_step(p["mixer"], cfg, h, c)
-                new_c[f"pos{pos}"] = st
+                    p["mixer"], cfg, h, positions, cache[key]["k"],
+                    cache[key]["v"], idx, seq_lens, tp, impl=impl)
+                cache[key] = {"k": ck, "v": cv}
+            else:
+                step = {"mamba": mamba_mod.mamba_decode_step,
+                        "mlstm": xlstm_mod.mlstm_decode_step,
+                        "slstm": xlstm_mod.slstm_decode_step}[mixer]
+                c = jax.tree.map(
+                    lambda t: jax.lax.dynamic_index_in_dim(
+                        t, idx, 0, keepdims=False), cache[key])
+                out, st = step(p["mixer"], cfg, h, c)
+                cache[key] = jax.tree.map(
+                    lambda full, s: jax.lax.dynamic_update_index_in_dim(
+                        full, s.astype(full.dtype), idx, 0), cache[key], st)
             x = x + out
             if cfg.ffn_at(pos) != "none":
                 h = rmsnorm(x, p["norm2"]["w"], cfg.norm_eps)
@@ -424,9 +425,6 @@ def decode_forward(params, cfg, inputs, positions, cache, seq_lens,
                     y = moe_mod.moe_ffn(p["ffn"], cfg, h,
                                         constrain=constrain, dropless=True)
                 x = x + y
-        cache = jax.tree.map(
-            lambda full, nc: jax.lax.dynamic_update_index_in_dim(
-                full, nc.astype(full.dtype), idx, 0), cache, new_c)
         return (x, cache), None
 
     P_ = cfg.num_periods

@@ -9,18 +9,27 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import dataclasses
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.config import get_config
+from repro.core import executor as X
 from repro.kernels.flash_prefill import flash_prefill
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.ops import DENSE_PAGE
+from repro.kernels.paged_attention import kv_append, paged_attention
 from repro.kernels.ssm_scan import ssm_scan
 from repro.kernels.unified_pd import unified_pd
+from repro.models.transformer import init_cache
 
-PAGE = 16
+PAGE = 16           # unified_pd's (Hkv, N, page, D) pages
+# the benchmark's configurations at their serving shapes: (slots, slot len)
+SERVED = {"starcoder2-3b": (64, 2048), "minicpm-2b": (8, 2048)}
 
 
 @pytest.fixture(scope="module")
@@ -69,20 +78,36 @@ def test_flash_prefill_compiles(one_chip, sc2):
     assert _has_kernel(c)
 
 
-def _decode_args(cfg, one_chip, B=8, max_seq=2048):
+def _decode_args(cfg, one_chip, B=8, max_seq=2048, page=DENSE_PAGE):
+    """Decode over (Hkv, N, D, page) pages, tokens on the lanes."""
     D, Hkv = cfg.head_dim, cfg.num_kv_heads
-    mp = max_seq // PAGE
+    mp = max_seq // page
     q = _sds((B, cfg.num_heads, D), jnp.bfloat16, one_chip)
-    pages = _sds((Hkv, B * mp, PAGE, D), jnp.bfloat16, one_chip)
+    pages = _sds((Hkv, B * mp, D, page), jnp.bfloat16, one_chip)
     tables = _sds((B, mp), jnp.int32, one_chip)
     lens = _sds((B,), jnp.int32, one_chip)
     return q, pages, tables, lens
 
 
-def test_paged_attention_compiles(one_chip, sc2):
-    q, pages, tables, lens = _decode_args(sc2, one_chip)
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_paged_attention_compiles(one_chip, arch):
+    q, pages, tables, lens = _decode_args(get_config(arch), one_chip)
     c = paged_attention.lower(q, pages, pages, tables, lens,
                               interpret=False).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_kv_append_compiles(one_chip, arch):
+    cfg = get_config(arch)
+    B, S = SERVED[arch]
+    Hkv, D = cfg.num_kv_heads, cfg.head_dim
+    cache = _sds((2, Hkv, B, D, S), jnp.bfloat16, one_chip)
+    new = _sds((B, Hkv, D), jnp.bfloat16, one_chip)
+    c = kv_append.lower(cache, cache, new, new, _sds((B,), jnp.int32,
+                                                     one_chip),
+                        _sds((), jnp.int32, one_chip), page=DENSE_PAGE,
+                        interpret=False).compile()
     assert _has_kernel(c)
 
 
@@ -90,11 +115,83 @@ def test_unified_pd_compiles(one_chip, sc2):
     D, Sp = sc2.head_dim, 512
     q_p = _sds((1, sc2.num_heads, Sp, D), jnp.bfloat16, one_chip)
     kv_p = _sds((1, sc2.num_kv_heads, Sp, D), jnp.bfloat16, one_chip)
-    q_d, pages, tables, lens = _decode_args(sc2, one_chip)
+    q_d, _, tables, lens = _decode_args(sc2, one_chip, page=PAGE)
+    pages = _sds((sc2.num_kv_heads, tables.shape[0] * tables.shape[1],
+                  PAGE, D), jnp.bfloat16, one_chip)
     c = unified_pd.lower(q_p, kv_p, kv_p, q_d, pages, pages, tables, lens,
                          f_decode=0.5, block_q=512, block_k=512,
                          interpret=False).compile()
     assert _has_kernel(c)
+
+
+# an HLO value: "%name = <type> op(operands" or a computation's
+# "name: <type>" parameter
+_DEF = re.compile(r"%?([\w.\-]+) = (.*?) ([\w\-]+)\(([^)]*)")
+_PARAM = re.compile(r"([\w.\-]+): (\w+\[[\d,]*\])")
+_SHAPE = re.compile(r"\w+\[([\d,]*)\]")
+_COPIES = ("copy", "copy-start", "copy-done", "transpose", "dynamic-slice")
+
+
+def _dims(type_text):
+    """The array shapes of an HLO type, each as its sorted dims above 1
+    (so a relayout, a bitcast or a unit axis leaves it the same)."""
+    return {tuple(sorted(int(d) for d in dims.split(",") if d not in
+                         ("", "1")))
+            for dims in _SHAPE.findall(type_text)}
+
+
+def _slab_traffic(hlo: str, slab, cache):
+    """Ops of ``hlo`` that move a layer's K/V slab (dims ``slab``) or
+    the whole cache (``cache``): any value of a slab's shape, a copy,
+    transpose or slice of either, and a dynamic-update-slice whose
+    update is either.  An in-place column update of the cache is none
+    of these."""
+    slab, cache = tuple(sorted(slab)), tuple(sorted(cache))
+    shapes = {}
+    bad = []
+    for line in hlo.splitlines():
+        for name, t in _PARAM.findall(line):
+            shapes[name] = _dims(t)
+            if slab in shapes[name]:
+                bad.append(line.strip()[:160])
+        m = _DEF.search(line)
+        if not m:
+            continue
+        name, t, op, operands = m.groups()
+        shapes[name] = _dims(t)
+        args = [a.strip().lstrip("%") for a in operands.split(",")]
+        moved = shapes[name]
+        if op == "dynamic-update-slice":
+            moved = shapes.get(args[1], set())
+        elif op not in _COPIES:
+            moved = moved & {slab}
+        if moved & {slab, cache}:
+            bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("arch", sorted(SERVED))
+def test_decode_step_updates_cache_in_place(one_chip, arch):
+    """The served decode program at the benchmark's widths and slot shape
+    (2 layers) writes each new K/V column into the stacked cache and the
+    kernel reads it there: no layer's slab is sliced out, relaid, copied
+    or written back."""
+    cfg = dataclasses.replace(get_config(arch), num_layers=2)
+    B, S = SERVED[arch]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(X._init_params, cfg=cfg), X.param_key(0)))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, B, S)))
+    ints = _sds((B,), jnp.int32, one_chip)
+    hlo = X._decode_step.lower(params, cache, ints, ints, cfg=cfg,
+                               impl="pallas").compile().as_text()
+    assert "paged_attention" in hlo and "kv_append" in hlo
+    k = cache["pos0"]["k"]
+    assert k.shape == (2, cfg.num_kv_heads, B, cfg.head_dim, S)
+    assert _slab_traffic(hlo, k.shape[1:], k.shape) == []
 
 
 def test_ssm_scan_compiles(one_chip):

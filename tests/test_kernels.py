@@ -6,7 +6,7 @@ import pytest
 
 from repro.kernels import ref
 from repro.kernels.flash_prefill import flash_prefill
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import kv_append, paged_attention
 from repro.kernels.ssm_scan import ssm_scan
 from repro.kernels.unified_pd import build_slot_schedule, unified_pd
 
@@ -53,13 +53,15 @@ def test_flash_prefill(rng, B, Hq, Hkv, S, D, bq, bk, window, dtype):
     (3, 8, 4, 64, 16, 6, 32),
     (1, 4, 1, 16, 8, 3, 8),
     (4, 2, 2, 32, 4, 5, 24),
+    (2, 4, 4, 64, 16, 4, 16),       # MHA (G=1), D=64
+    (3, 3, 3, 64, 8, 5, 24),        # MHA, odd head count
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_attention(rng, B, Hq, Hkv, D, page, max_pages, N, dtype):
     ks = jax.random.split(rng, 3)
     q = _rand(ks[0], (B, Hq, D), dtype)
-    kp = _rand(ks[1], (Hkv, N, page, D), dtype)
-    vp = _rand(ks[2], (Hkv, N, page, D), dtype)
+    kp = _rand(ks[1], (Hkv, N, D, page), dtype)
+    vp = _rand(ks[2], (Hkv, N, D, page), dtype)
     rs = np.random.RandomState(0)
     tabs = jnp.asarray(np.stack(
         [rs.permutation(N)[:max_pages] for _ in range(B)]).astype(np.int32))
@@ -76,12 +78,40 @@ def test_paged_attention_len_one(rng):
     B, Hq, Hkv, D, page, mp, N = 2, 4, 2, 32, 8, 3, 8
     ks = jax.random.split(rng, 3)
     q = _rand(ks[0], (B, Hq, D), jnp.float32)
-    kp = _rand(ks[1], (Hkv, N, page, D), jnp.float32)
-    vp = _rand(ks[2], (Hkv, N, page, D), jnp.float32)
+    kp = _rand(ks[1], (Hkv, N, D, page), jnp.float32)
+    vp = _rand(ks[2], (Hkv, N, D, page), jnp.float32)
     tabs = jnp.tile(jnp.arange(mp, dtype=jnp.int32), (B, 1))
     lens = jnp.array([1, page * mp], jnp.int32)
     out = paged_attention(q, kp, vp, tabs, lens, interpret=True)
     want = ref.paged_attention(q, kp, vp, tabs, lens)
+    np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("L,layer,Hq,Hkv,D,page,npr", [
+    (3, 2, 4, 2, 32, 8, 4),         # rows of 4 pages, GQA
+    (2, 1, 4, 4, 64, 16, 2),        # MHA, D=64
+    (4, 0, 2, 1, 16, 8, 1),         # one page per row: a page pool
+])
+def test_paged_attention_stacked_layer(rng, L, layer, Hq, Hkv, D, page, npr):
+    """A stacked cache (L, Hkv, R, D, npr*page) read at ``layer`` equals
+    that layer's pages, unrolled to a (Hkv, R*npr, D, page) pool."""
+    B, R, mp = 3, 5, 3
+    ks = jax.random.split(rng, 3)
+    q = _rand(ks[0], (B, Hq, D), jnp.float32)
+    kc = _rand(ks[1], (L, Hkv, R, D, npr * page), jnp.float32)
+    vc = _rand(ks[2], (L, Hkv, R, D, npr * page), jnp.float32)
+    rs = np.random.RandomState(2)
+    tabs = jnp.asarray(np.stack(
+        [rs.permutation(R * npr)[:mp] for _ in range(B)]).astype(np.int32))
+    lens = jnp.array([1, page + 3, mp * page], jnp.int32)
+    out = paged_attention(q, kc, vc, tabs, lens, jnp.int32(layer),
+                          page=page, interpret=True)
+
+    def pool(c):
+        return c[layer].reshape(Hkv, R, D, npr, page).transpose(
+            0, 1, 3, 2, 4).reshape(Hkv, R * npr, D, page)
+
+    want = ref.paged_attention(q, pool(kc), pool(vc), tabs, lens)
     np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
 
 
@@ -170,6 +200,40 @@ def test_unified_pd_matches_single_kernels(rng):
                           interpret=True)
     o_p2 = flash_prefill(q_p, k_p, v_p, block_q=32, block_k=32,
                          interpret=True)
-    o_d2 = paged_attention(q_d, kpg, vpg, tabs, lens, interpret=True)
+    # the fused kernel keeps (Hkv, N, page, D) pages; paged_attention
+    # reads (Hkv, N, D, page)
+    o_d2 = paged_attention(q_d, kpg.swapaxes(2, 3), vpg.swapaxes(2, 3),
+                           tabs, lens, interpret=True)
     np.testing.assert_allclose(o_p, o_p2, atol=1e-6, rtol=1e-6)
     np.testing.assert_allclose(o_d, o_d2, atol=1e-6, rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# kv_append — decode's in-place column write
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("L,layer,Hkv,B,D,S,page", [
+    (2, 1, 2, 3, 32, 64, 16),       # rows of 4 pages, GQA widths
+    (3, 0, 4, 2, 64, 32, 32),       # MHA, D=64, one page per row
+    (4, 3, 1, 4, 16, 48, 16),
+])
+def test_kv_append(rng, L, layer, Hkv, B, D, S, page):
+    """Equals a scatter of each slot's column into the stacked cache;
+    every other element keeps its value.  Slots past the row clamp to
+    its last token, as a dynamic_update_slice would."""
+    ks = jax.random.split(rng, 4)
+    kc = _rand(ks[0], (L, Hkv, B, D, S), jnp.bfloat16)
+    vc = _rand(ks[1], (L, Hkv, B, D, S), jnp.bfloat16)
+    kn = _rand(ks[2], (B, Hkv, D), jnp.float32)
+    vn = _rand(ks[3], (B, Hkv, D), jnp.float32)
+    slot = jnp.asarray(np.random.RandomState(3).randint(0, S, size=B),
+                       jnp.int32).at[-1].set(S + 5)
+    ko, vo = kv_append(kc, vc, kn, vn, slot, jnp.int32(layer), page=page,
+                       interpret=True)
+    at = jnp.minimum(slot, S - 1)
+    b = jnp.arange(B)
+    want_k = kc.at[layer, :, b, :, at].set(kn.astype(kc.dtype))
+    want_v = vc.at[layer, :, b, :, at].set(vn.astype(vc.dtype))
+    np.testing.assert_array_equal(ko, want_k)
+    np.testing.assert_array_equal(vo, want_v)

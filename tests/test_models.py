@@ -1,6 +1,9 @@
 """Per-arch smoke tests (reduced configs) + decode/forward consistency."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.config import get_reduced_config, list_archs
@@ -133,7 +136,7 @@ def test_sliding_window_ring_cache(rng):
     x, pos = _inputs(cfg, rng, B, S)
     logits, aux = forward(params, cfg, x, pos, 1, return_aux=True)
     cache = init_cache(cfg, B, 64, 1)
-    assert cache["pos0"]["k"].shape[3] == cfg.sliding_window
+    assert cache["pos0"]["k"].shape[4] == cfg.sliding_window
     cache = write_prefill_to_cache(cfg, cache, aux, S)
     nxt = greedy_sample(logits[:, -1:], cfg.vocab_size)
     dl, _ = decode_forward(params, cfg, nxt,
@@ -165,3 +168,61 @@ def test_moe_active_params():
     cfg = get_config("qwen3-moe-235b-a22b")
     active = cfg.active_param_count()
     assert 15e9 < active < 30e9   # the "A22B" in the name
+
+
+def test_decode_writes_each_slot_column_in_place(rng):
+    """Slots at different lengths through several decode steps (the
+    kernel in interpret mode): each step writes each slot's K/V column
+    at ``lens[b]`` of every layer, equal to what ``forward`` computes at
+    that position, and leaves every other position as it was."""
+    cfg = dataclasses.replace(get_reduced_config("starcoder2-3b"),
+                              dtype="float32")
+    params, _ = init_model(rng, cfg, tp=1)
+    lens0, T, Sc = [5, 17, 11], 3, 32
+    B = len(lens0)
+    seqs = [jax.random.randint(jax.random.fold_in(rng, b), (1, n), 0,
+                               cfg.vocab_size) for b, n in enumerate(lens0)]
+    # each slot prefilled alone, then noise past its prompt: the
+    # positions decode must leave alone hold something to compare
+    slots = []
+    for x in seqs:
+        n = x.shape[1]
+        _, aux = forward(params, cfg, x, jnp.arange(n)[None], 1,
+                         return_aux=True)
+        slots.append(write_prefill_to_cache(cfg, init_cache(cfg, 1, Sc),
+                                            aux, n))
+    cache = jax.tree.map(lambda *c: jnp.concatenate(c, axis=2), *slots)
+    noise = jax.random.normal(jax.random.fold_in(rng, 99),
+                              cache["pos0"]["k"].shape)
+    past = (jnp.arange(Sc) >= jnp.array(lens0)[:, None])[None, None, :,
+                                                         None, :]
+    cache = jax.tree.map(lambda c: jnp.where(past, noise, c), cache)
+    before = cache
+    lens = jnp.array(lens0, jnp.int32)
+    toks = jnp.array([int(x[0, -1]) for x in seqs], jnp.int32)[:, None]
+    fed = [[] for _ in range(B)]
+    for _ in range(T):
+        dl, cache = decode_forward(params, cfg, toks, lens[:, None], cache,
+                                   lens, 1, impl="interpret")
+        for b in range(B):
+            fed[b].append(int(toks[b, 0]))
+        toks = greedy_sample(dl, cfg.vocab_size)
+        lens = lens + 1
+    for b, x in enumerate(seqs):
+        # forward over the prompt then every fed token: position
+        # lens0[b] + t holds the t-th fed token's K/V
+        full = jnp.concatenate([x, jnp.array(fed[b], jnp.int32)[None]], 1)
+        _, aux = forward(params, cfg, full, jnp.arange(full.shape[1])[None],
+                         1, return_aux=True)
+        for name in ("k", "v"):
+            got = cache["pos0"][name][:, :, b]          # (P, H, D, Sc)
+            want = aux["pos0"][name][:, 0].transpose(0, 2, 3, 1)
+            new = slice(lens0[b], lens0[b] + T)
+            np.testing.assert_allclose(got[..., new], want[..., new],
+                                       atol=1e-4, rtol=1e-4)
+    written = ((jnp.arange(Sc) >= jnp.array(lens0)[:, None])
+               & (jnp.arange(Sc) < jnp.array(lens0)[:, None] + T))
+    keep = ~written[None, None, :, None, :]
+    for name in ("k", "v"):
+        a, b_ = cache["pos0"][name], before["pos0"][name]
+        assert bool(jnp.all(jnp.where(keep, a == b_, True))), name
