@@ -2,15 +2,15 @@
 
 After the window closes, a sample drawn from the seed of the requests
 the program served (finished, or cut at the close with the tokens
-streamed so far; the longest among them; in a fleet, the longest of
-each replica and at least one more of each) is run through the plain
-reference (``reference.py``) over its prompt and served tokens, teacher
-forced.  At each served token the number compared is the gap by which
-the reference's logit of that token lies below the reference's best
-logit there.  The first served token comes from the prefill program
-(``flash_prefill``), the rest from decode steps through the slot cache
-(``paged_attention``) after the slot insert, all sampled greedily, so
-every layer the cells name is covered.
+streamed so far; the longest among them; in a fleet, the longest of each
+replica and at least one more of each) is run through the plain
+reference the configuration names (``run.ref``, ``references/``) over
+its prompt and served tokens, teacher forced.  At each served token the
+number compared is the gap by which the reference's logit of that token
+lies below the reference's best logit there.  The first served token
+comes from the prefill program (``flash_prefill``), the rest from decode
+steps through the slot cache (``paged_attention``) after the slot
+insert, all sampled greedily, so every layer the cells name is covered.
 
 ``control`` reads the fp8 control on the same rows, the gap of the token
 that the fp8 forward puts first, and judges it by the same limits: a
@@ -111,7 +111,6 @@ def judge(run, served: Dict[int, dict], model: dict, seed: int, device,
     """The run's verdict; with ``control``, the fp8 control's beside it
     under ``control``, read on the same rows and held to the same
     limits."""
-    from bench import reference
     mismatch = sum(1 for s in served.values()
                    if s["ids"] is None or s["streamed"] != s["output_len"]
                    or len(s["ids"]) < s["prompt_len"] + s["output_len"])
@@ -135,9 +134,9 @@ def judge(run, served: Dict[int, dict], model: dict, seed: int, device,
     if rows:
         alt = None
         if control:
-            alt = reference.forward(model, seed, toks, quant=True,
-                                    device=device).argmax
-        ref = reference.forward(model, seed, toks, alt=alt, device=device)
+            alt = run.ref.forward(model, seed, toks, quant=True,
+                                  device=device).argmax
+        ref = run.ref.forward(model, seed, toks, alt=alt, device=device)
         for i, a, b in spans:
             gaps.append(ref.best[i, a:b] - ref.at_next[i, a:b])
             compared += b - a

@@ -6,9 +6,9 @@ gap on many seeds, and the fp8 control's on some, in one process.
 
 Each seed is a whole run of the cell at its own load (``run_cell``) for
 ``--seconds``; on a control seed the same sample is also read through
-the reference at fp8 (``reference.forward(quant=True)``), as the gap of
-the token the fp8 forward puts first, and judged by the same limits as
-the program (``check.verdict``): ``control_correct`` has to come out
+the configuration's reference at fp8 (its ``forward(quant=True)``), as
+the gap of the token the fp8 forward puts first, and judged by the same
+limits as the program (``check.verdict``): ``control_correct`` has to come out
 false.  One JSON line per seed.  The benchmark's own runs never run the
 control.
 """

@@ -7,6 +7,9 @@ Found by name, so that a later cell adds files and edits none:
 * ``<bench>/configs/<config>.json``: the model (its published keys), the
   block the reference computes, the ``ServeConfig``, the router and the
   limits of the check;
+* ``<bench>/references/<name>.py``: the plain reference the
+  configuration names (``"reference"``, else ``dense``), with its layout,
+  weight laws and work counts (``references/__init__.py``);
 * ``<bench>/traffic/<mix>.json``: arrivals and lengths (``traffic.py``);
 * ``<bench>/metrics/<metric>.py``: ``reduce(run) -> float | None``.
 
@@ -16,8 +19,9 @@ files are looked for first.
 The served stack is the one users reach: ``POST /v1/generate`` on
 ``GatewayHTTPServer`` over a ``Gateway`` on the real-time clock, one
 ``rapid`` worker per chip, each with a ``DeviceExecutor`` whose weights
-are replaced by ``bench/weights.py``'s from the run's seed.  The load
-generator is a child process (``client.py``) that never imports JAX.
+are replaced by ``bench/weights.py``'s from the run's seed, in the
+layout the reference computes.  The load generator is a child process
+(``client.py``) that never imports JAX.
 """
 from __future__ import annotations
 
@@ -128,7 +132,7 @@ class Run:
     """What a metric's ``reduce`` reads."""
     cell: dict
     model: dict
-    dims: object                  # bench.reference.Dims
+    dims: object                  # the reference's dims_of(model)
     peaks: dict
     chips: int
     seconds: float
@@ -138,6 +142,7 @@ class Run:
     setup_s: float
     replica_of: Dict[int, int]    # rid -> replica
     trace: object = None          # bench.trace.Trace (traced runs)
+    ref: object = None            # the configuration's reference module
     # where host-side per-layer metrics are read: the window, or in a
     # traced run the part of it before the profiler started
     host_window: Optional[tuple] = None
@@ -174,29 +179,6 @@ def percentile(vals: Sequence[float], q: float) -> Optional[float]:
 
 # -- the served stack ------------------------------------------------------------
 
-def check_registry(cfg, dims) -> None:
-    """The program's registry entry must be the configuration file's."""
-    have = {"d_model": cfg.d_model, "num_heads": cfg.num_heads,
-            "num_kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
-            "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
-            "num_layers": cfg.num_layers, "ffn_glu": cfg.ffn_glu,
-            "qkv_bias": cfg.qkv_bias, "tie": cfg.tie_embeddings,
-            "norm_eps": cfg.norm_eps, "rope_theta": float(cfg.rope_theta),
-            "dtype": cfg.dtype, "act": cfg.act}
-    want = {"d_model": dims.d, "num_heads": dims.heads,
-            "num_kv_heads": dims.kv_heads, "head_dim": dims.head_dim,
-            "d_ff": dims.ffn, "vocab_size": dims.vocab,
-            "num_layers": dims.layers, "ffn_glu": dims.glu,
-            "qkv_bias": dims.qkv_bias, "tie": dims.tied,
-            "norm_eps": dims.eps, "rope_theta": dims.theta,
-            "dtype": dims.dtype,
-            "act": "silu" if dims.glu else "gelu"}
-    diff = {k: (have[k], want[k]) for k in want if have[k] != want[k]}
-    if diff or cfg.sliding_window:
-        raise SetupError(f"{cfg.name}: the program's config differs from "
-                         f"the configuration file: {diff}")
-
-
 def model_config(model: dict):
     from repro.config import get_config, get_reduced_config
     arch = model["arch"]
@@ -214,14 +196,27 @@ def build_executors(cfg, serve, devices, seed: int, interpret: bool):
         return list(pool.map(make, devices))
 
 
-def install_weights(ex, seed: int, dims) -> None:
+def reference_of(files: Files, model: dict):
+    """The reference module the configuration names."""
+    return files.module("references", model.get("reference", "dense"))
+
+
+def check_registry(ref, cfg, dims) -> None:
+    """The program's registry entry must be the block ``ref`` computes."""
+    try:
+        ref.check_registry(cfg, dims)
+    except ValueError as e:
+        raise SetupError(str(e)) from e
+
+
+def install_weights(ex, seed: int, ref, dims) -> None:
     """Replace the executor's weights by the benchmark's, from ``seed``."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
-    from bench import reference, weights
+    from bench import weights
     names, leaves, _ = weights.paths(ex.params)
-    want = reference.layout(dims)
+    want = ref.layout(dims)
     have = {n: (l.shape[1:] if n.startswith("layers/") else l.shape)
             for n, l in zip(names, leaves)}
     if have != {k: tuple(v) for k, v in want.items()}:
@@ -233,7 +228,8 @@ def install_weights(ex, seed: int, dims) -> None:
         leaf.delete()
     ex.params = None
     ex.params = weights.make_params(like, seed,
-                                    SingleDeviceSharding(ex.device))
+                                    SingleDeviceSharding(ex.device),
+                                    getattr(ref, "weight_law", None))
 
 
 def instrument(ex, chip: int, steps: List[Step], tracing: bool) -> None:
@@ -360,6 +356,7 @@ class Stack:
     model: dict
     mix: dict
     metrics: dict
+    ref: object
     dims: object
     peaks: dict
     devices: list
@@ -376,7 +373,6 @@ def build_stack(workload: str, seed: int, trace: bool, *, files: Files,
     enough for the cell; tests pass CPU devices with ``interpret=True``."""
     import jax
 
-    from bench import reference
     cell, metrics = cell_of(files, workload)
     model = files.json("configs", cell["config"])
     mix = files.json("traffic", cell["traffic"])
@@ -394,14 +390,15 @@ def build_stack(workload: str, seed: int, trace: bool, *, files: Files,
 
     from repro.config import ServeConfig
     from repro.serving import Gateway, RealTimeClock
-    dims = reference.dims_of(model)
+    ref = reference_of(files, model)
+    dims = ref.dims_of(model)
     cfg = model_config(model)
-    check_registry(cfg, dims)
+    check_registry(ref, cfg, dims)
     serve = ServeConfig(mode="rapid", chips=1, **model["serve"])
     executors = build_executors(cfg, serve, devices, seed, interpret)
     steps: List[Step] = []
     for chip, ex in enumerate(executors):
-        install_weights(ex, seed, dims)
+        install_weights(ex, seed, ref, dims)
         instrument(ex, chip, steps, trace)
     jax.block_until_ready([ex.params for ex in executors])
     gw = Gateway(cfg, serve, modes=(),
@@ -416,8 +413,8 @@ def build_stack(workload: str, seed: int, trace: bool, *, files: Files,
             with jax.profiler.TraceAnnotation("bench.submit"):
                 return inner_submit(r, **kw)
         gw.submit = submit
-    return Stack(cell, model, mix, metrics, dims, peaks, devices, executors,
-                 gw, steps)
+    return Stack(cell, model, mix, metrics, ref, dims, peaks, devices,
+                 executors, gw, steps)
 
 
 def serve_plan(stack: Stack, plan: dict, tracer=None) -> dict:
@@ -471,7 +468,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
               peaks=stack.peaks, chips=len(devices), seconds=seconds,
               window=(t0, t1), requests=result["requests"],
               steps=stack.steps, setup_s=t0 - t_start,
-              replica_of=replicas(stack),
+              replica_of=replicas(stack), ref=stack.ref,
               closed_loop=plan["mode"] == "closed")
     if tracer and tracer.t_start is not None:
         run.host_window = (t0, tracer.t_start)

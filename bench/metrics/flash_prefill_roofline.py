@@ -2,7 +2,6 @@
 causal attention over the valid prompt tokens (the larger of its FLOPs
 over peak FLOP/s and its bytes over peak bandwidth) over the kernel's
 device time in the trace."""
-from bench import work
 from bench.breakdown import kernel_ns, traced_steps
 
 
@@ -16,9 +15,9 @@ def reduce(run):
         if step.prefill and t > 0:
             for _, n in step.prefill:
                 least += max(
-                    work.prefill_attention_flops(run.dims, n)
+                    run.ref.prefill_attention_flops(run.dims, n)
                     / pk["bf16_flops_per_s"],
-                    work.flash_prefill_bytes(run.dims, n)
+                    run.ref.flash_prefill_bytes(run.dims, n)
                     / pk["hbm_bytes_per_s"])
             ns += t
     return least / (ns * 1e-9) * 100 if ns else None

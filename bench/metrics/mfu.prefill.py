@@ -1,6 +1,5 @@
 """Model FLOPs of the valid prompt tokens over the device time of the
 prefill programs that computed them, as a share of peak bf16 FLOP/s."""
-from bench import work
 from bench.breakdown import program_ns, traced_steps
 
 
@@ -11,7 +10,7 @@ def reduce(run):
     for step, span in traced_steps(run):
         t = program_ns(run, step, span, "_prefill_step")
         if step.prefill and t > 0:
-            flops += sum(work.prefill_flops(run.dims, n)
+            flops += sum(run.ref.prefill_flops(run.dims, n)
                          for _, n in step.prefill)
             ns += t
     if ns == 0:

@@ -2,7 +2,6 @@
 the live KV of each decode step (sum of attended lengths times KV bytes
 per token, plus q and out) at peak bandwidth, or its FLOPs at peak if
 longer, over the kernel's device time in the trace."""
-from bench import work
 from bench.breakdown import kernel_ns, traced_steps
 
 
@@ -15,9 +14,9 @@ def reduce(run):
         t = kernel_ns(run, step, span, "paged_attention")
         if step.decode_lens and t > 0:
             least += max(
-                work.paged_attention_bytes(run.dims, step.decode_lens)
+                run.ref.paged_attention_bytes(run.dims, step.decode_lens)
                 / pk["hbm_bytes_per_s"],
-                work.attention_flops(run.dims, step.decode_lens)
+                run.ref.attention_flops(run.dims, step.decode_lens)
                 / pk["bf16_flops_per_s"])
             ns += t
     return least / (ns * 1e-9) * 100 if ns else None

@@ -1,14 +1,16 @@
-"""Plain float32 reference of the served decoder, and its fp8 control.
+"""The dense decoder's plain float32 reference, its fp8 control, and
+the work counts of its steps and kernels.
 
-Written from the configuration file alone (``bench/configs/*.json``):
-it imports nothing of the program and takes none of its arrays.  The
-weights come from ``bench/weights.py`` with the run's seed, one layer at
-a time, at the values the served model holds (bfloat16, widened).
+Written from the configuration file alone (``configs/*.json``): it
+imports nothing of the program and takes none of its arrays.  The
+weights come from ``weights.py`` with the run's seed, one layer at a
+time, at the values the served model holds (bfloat16, widened).
 
 The block is the one the configuration file states: pre-norm RMSNorm,
 rotary attention (half-split rotation) with grouped KV heads and
 optional q/k/v biases, then an MLP (tanh-GELU, or SiLU-gated), a final
 RMSNorm and the LM head (tied to the embedding where the file says so).
+Full causal attention in every layer, a dense MLP in every layer.
 Every matrix product runs at ``Precision.HIGHEST``: on a TPU a float32
 product is otherwise computed in bfloat16 passes.
 
@@ -16,12 +18,18 @@ product is otherwise computed in bfloat16 passes.
 matrix and every matrix input rounded to float8 (e4m3, per output
 channel and per token, with float32 accumulation), the lower precision
 a later change to bfloat16 serving would be tempted by.
+
+Work counts are of the algorithm, not of an implementation: bucket
+padding, free slots and dead pages are not counted, so a roofline share
+reads the same work whatever computes it.  A multiply-add is two FLOPs;
+bfloat16 weights and KV (two bytes an element) are what the
+configurations serve.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, Iterable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +94,30 @@ def layout(dims: Dims) -> Dict[str, tuple]:
     for k, v in lay.items():
         out[f"layers/pos0/{k}"] = v
     return out
+
+
+def check_registry(cfg, dims: Dims) -> None:
+    """The program's registry entry must be the configuration file's
+    dense block: the same widths and switches, and no sliding window."""
+    have = {"d_model": cfg.d_model, "num_heads": cfg.num_heads,
+            "num_kv_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+            "d_ff": cfg.d_ff, "vocab_size": cfg.vocab_size,
+            "num_layers": cfg.num_layers, "ffn_glu": cfg.ffn_glu,
+            "qkv_bias": cfg.qkv_bias, "tie": cfg.tie_embeddings,
+            "norm_eps": cfg.norm_eps, "rope_theta": float(cfg.rope_theta),
+            "dtype": cfg.dtype, "act": cfg.act}
+    want = {"d_model": dims.d, "num_heads": dims.heads,
+            "num_kv_heads": dims.kv_heads, "head_dim": dims.head_dim,
+            "d_ff": dims.ffn, "vocab_size": dims.vocab,
+            "num_layers": dims.layers, "ffn_glu": dims.glu,
+            "qkv_bias": dims.qkv_bias, "tie": dims.tied,
+            "norm_eps": dims.eps, "rope_theta": dims.theta,
+            "dtype": dims.dtype,
+            "act": "silu" if dims.glu else "gelu"}
+    diff = {k: (have[k], want[k]) for k in want if have[k] != want[k]}
+    if diff or cfg.sliding_window:
+        raise ValueError(f"{cfg.name}: the program's config differs from "
+                         f"the configuration file: {diff}")
 
 
 def _q8(x, axis):
@@ -200,3 +232,93 @@ def forward(model: dict, seed: int, tokens: np.ndarray,
             for b in range(tokens.shape[0])]
     return Reading(*(np.stack([np.asarray(o[i]) for o in outs])
                      for i in range(4)))
+
+
+# -- work counts --------------------------------------------------------------
+
+BYTES = 2
+
+
+def layer_matmul_params(dims) -> int:
+    """Weights of one block's matrix products (biases and norms apart)."""
+    d, D = dims.d, dims.head_dim
+    attn = d * dims.heads * D * 2 + d * dims.kv_heads * D * 2
+    ffn = d * dims.ffn * (3 if dims.glu else 2)
+    return attn + ffn
+
+
+def layer_vector_params(dims) -> int:
+    v = 2 * dims.d
+    if dims.qkv_bias:
+        v += (dims.heads + 2 * dims.kv_heads) * dims.head_dim
+    return v
+
+
+def weight_params(dims) -> int:
+    """Every weight the served model holds (padded vocabulary rows too)."""
+    emb = dims.vocab_padded * dims.d
+    head = 0 if dims.tied else dims.d * dims.vocab_padded
+    return dims.layers * (layer_matmul_params(dims) + layer_vector_params(
+        dims)) + emb + head + dims.d
+
+
+def weight_bytes(dims) -> int:
+    return weight_params(dims) * BYTES
+
+
+def kv_bytes_per_token(dims) -> int:
+    """K and V of one token in every layer."""
+    return 2 * dims.layers * dims.kv_heads * dims.head_dim * BYTES
+
+
+def head_flops(dims) -> int:
+    """LM head over the real vocabulary, one token."""
+    return 2 * dims.d * dims.vocab
+
+
+def attention_flops(dims, q_positions: Iterable[int]) -> int:
+    """QK^T and PV of one query at each attended length, all layers."""
+    per = 4 * dims.heads * dims.head_dim * dims.layers
+    return per * sum(q_positions)
+
+
+def prefill_attention_flops(dims, n: int) -> int:
+    """Causal attention over ``n`` valid prompt tokens: query i attends
+    i + 1 keys."""
+    return 4 * dims.heads * dims.head_dim * dims.layers * n * (n + 1) // 2
+
+
+def prefill_flops(dims, n: int) -> int:
+    """One prompt of ``n`` valid tokens; the head runs on the last one."""
+    return (2 * layer_matmul_params(dims) * dims.layers * n
+            + prefill_attention_flops(dims, n) + head_flops(dims))
+
+
+def decode_flops(dims, lens: Iterable[int]) -> int:
+    """One token for each live slot; ``lens`` are the lengths each new
+    token attends (cache plus itself)."""
+    lens = list(lens)
+    return (len(lens) * (2 * layer_matmul_params(dims) * dims.layers
+                         + head_flops(dims))
+            + attention_flops(dims, lens))
+
+
+def paged_attention_bytes(dims, lens: Iterable[int]) -> int:
+    """Live KV read by one decode step's attention, plus q and out."""
+    lens = list(lens)
+    kv = sum(lens) * kv_bytes_per_token(dims)
+    q_out = 2 * len(lens) * dims.heads * dims.head_dim * dims.layers * BYTES
+    return kv + q_out
+
+
+def flash_prefill_bytes(dims, n: int) -> int:
+    """q, k, v in and out once, all layers."""
+    return (2 * dims.heads + 2 * dims.kv_heads) * dims.head_dim * n \
+        * dims.layers * BYTES
+
+
+def decode_bound_s(dims, slots: int, seq_len: int, peak_bw: float) -> float:
+    """Least time of a decode step that reads every weight and a dense
+    slot cache of ``slots`` x ``seq_len`` tokens."""
+    return (weight_bytes(dims) + slots * seq_len * kv_bytes_per_token(dims)) \
+        / peak_bw

@@ -6,7 +6,7 @@ then one window per rate and traffic seed, lowest rate first.
 
 Each window is reduced by the cell's own metric files (``bench/metrics``)
 on a ``Run`` of the window, and of each half of it: the requests due and
-failed (``check.counts``), ``ttft_p95_ms`` over the window and over each
+failed (``check.counts``), ``ttft_tail_p95_ms`` over the window and over each
 half (a backlog that grows shows as a second half above the first),
 ``itl_p95_ms``, ``queue_wait_p95_ms``, and ``output_tok_s`` over the
 second half beside the tokens/s offered (rate x mean output).  A window
@@ -35,7 +35,8 @@ def summarize(files, stack, result, plan, rate):
                       peaks=stack.peaks, chips=len(stack.devices),
                       seconds=t1 - t0, window=(t0, t1),
                       requests=result["requests"], steps=stack.steps,
-                      setup_s=0.0, replica_of=harness.replicas(stack))
+                      setup_s=0.0, replica_of=harness.replicas(stack),
+                      ref=stack.ref)
     first = dataclasses.replace(run, window=(t0, mid))
     second = dataclasses.replace(run, window=(mid, t1))
 
@@ -45,13 +46,13 @@ def summarize(files, stack, result, plan, rate):
     offered = rate * sum(q[2] for q in plan["requests"]) / len(
         plan["requests"])
     due, failed = check.counts(run)
-    p95_first, p95_second = (metric("ttft_p95_ms", first),
-                             metric("ttft_p95_ms", second))
+    p95_first, p95_second = (metric("ttft_tail_p95_ms", first),
+                             metric("ttft_tail_p95_ms", second))
     tok_second = metric("output_tok_s", second)
     keeps = (failed == 0 and p95_first is not None and p95_second is not None
              and p95_second <= 1.25 * p95_first and tok_second >= 0.9 * offered)
     return {"rate": rate, "due": due, "failed": failed,
-            "ttft_p95_ms": metric("ttft_p95_ms", run),
+            "ttft_p95_ms": metric("ttft_tail_p95_ms", run),
             "ttft_p95_first_half_ms": p95_first,
             "ttft_p95_second_half_ms": p95_second,
             "itl_p95_ms": metric("itl_p95_ms", run),

@@ -87,7 +87,8 @@ def reduce(name, run):
 
 def test_end_to_end_arithmetic_by_hand():
     run = _run(_steady())
-    assert reduce("ttft_p95_ms", run) == pytest.approx(200.0)
+    assert reduce("ttft_p50_ms", run) == pytest.approx(200.0)
+    assert reduce("ttft_tail_p95_ms", run) == pytest.approx(200.0)
     assert reduce("itl_p95_ms", run) == pytest.approx(50.0)
     # tokens that arrived in [100, 110): all of request i arrive before
     # 110 while 0.5 i + 0.2 + 0.95 < 10, i.e. i <= 17; 18 and 19 partly
@@ -101,8 +102,11 @@ def test_end_to_end_arithmetic_by_hand():
 def test_a_stall_moves_every_end_to_end_metric():
     base = _run(_steady())
     stalled = _run(_steady(stall=0.2))
-    for name in ("ttft_p95_ms", "itl_p95_ms"):
+    for name in ("ttft_p50_ms", "ttft_tail_p95_ms", "itl_p95_ms"):
         assert reduce(name, stalled) > reduce(name, base) * 1.2, name
+    # request i waits 0.2 + 0.2 i s: the median of i = 0..19 and the 95th
+    assert reduce("ttft_p50_ms", stalled) == pytest.approx(2100.0)
+    assert reduce("ttft_tail_p95_ms", stalled) == pytest.approx(3810.0)
     assert reduce("output_tok_s", stalled) < reduce("output_tok_s", base)
 
 
@@ -148,6 +152,10 @@ def test_benchmark_names_files_for_every_entry():
     files = harness.Files()
     for c in bench["configs"]:
         assert (harness.ROOT / c["file"]).is_file()
+        model = json.loads((harness.ROOT / c["file"]).read_text())
+        ref = harness.reference_of(files, model)
+        for entry in ("dims_of", "layout", "check_registry", "forward"):
+            assert callable(getattr(ref, entry)), (c["name"], entry)
     for w in bench["workloads"]:
         files.find("traffic", w["traffic"], ".json")
     for m in bench["end_to_end"] + bench["per_layer"]:

@@ -52,14 +52,19 @@ def leaf_keys(seed: int, path: str, layer: int) -> tuple:
     return k1, k2
 
 
-def law(path: str, shape, tied: bool) -> tuple:
+def law(path: str, shape, tied: bool, extra=None) -> tuple:
     """(mean, amplitude) of the uniform law of one leaf; the amplitude is
     a power of two near sqrt(3) * the standard deviation wanted.  A tied
     embedding is also the head, so it gets the head's 1/sqrt(d): logits
-    of unit scale, where rounding can move the first choice."""
+    of unit scale, where rounding can move the first choice.  ``extra``
+    is a reference's ``weight_law``: its (mean, std) for a leaf, or None
+    to leave the leaf to the laws here."""
     name = path.rsplit("/", 1)[-1]
     parent = path.split("/")[-2] if "/" in path else ""
-    if name == "tok":
+    own = extra(path, shape, tied) if extra else None
+    if own is not None:
+        mean, std = own
+    elif name == "tok":
         std, mean = (1.0 / math.sqrt(shape[-1]) if tied else 1.0), 0.0
     elif name in MATRICES or parent == "lm_head":
         std, mean = 1.0 / math.sqrt(shape[-2]), 0.0
@@ -88,9 +93,10 @@ def paths(tree):
     return names, [leaf for _, leaf in flat], treedef
 
 
-def make_params(like, seed: int, sharding):
+def make_params(like, seed: int, sharding, extra=None):
     """A tree shaped and typed as ``like`` (arrays or ShapeDtypeStructs),
-    filled from ``seed`` in one jitted call, placed by ``sharding``."""
+    filled from ``seed`` in one jitted call, placed by ``sharding``;
+    ``extra`` as for ``law``."""
     names, leaves, treedef = paths(like)
     tied = "lm_head/w" not in names
     specs = []
@@ -100,7 +106,7 @@ def make_params(like, seed: int, sharding):
         n = leaf.shape[0] if stacked else 1
         keys = np.array([leaf_keys(seed, name, i) for i in range(n)],
                         np.uint32)
-        specs.append((stacked, tuple(per), keys, *law(name, per, tied),
+        specs.append((stacked, tuple(per), keys, *law(name, per, tied, extra),
                       jnp.dtype(leaf.dtype)))
 
     def build(key_arrays):
@@ -113,23 +119,25 @@ def make_params(like, seed: int, sharding):
     return jax.jit(build, out_shardings=sharding)([s[2] for s in specs])
 
 
-def layer_params(names_shapes, seed: int, layer: int, device=None):
+def layer_params(names_shapes, seed: int, layer: int, device=None,
+                 extra=None):
     """Float32 values of one layer's leaves, as ``{path: array}``.
     ``names_shapes`` maps each ``layers/...`` path to (per-layer shape,
     the dtype it is served in); the values are rounded to that dtype."""
     out = {}
     for name, (shape, served) in names_shapes.items():
         k1, k2 = leaf_keys(seed, name, layer)
-        mean, amp = law(name, shape, False)
+        mean, amp = law(name, shape, False, extra)
         out[name] = _leaf_f32(tuple(shape), np.uint32(k1), np.uint32(k2),
                               mean, amp, jnp.dtype(served), device)
     return out
 
 
-def leaf(name: str, shape, served, seed: int, tied: bool, device=None):
+def leaf(name: str, shape, served, seed: int, tied: bool, device=None,
+         extra=None):
     """Float32 values of one unstacked leaf (embedding, head, norm)."""
     k1, k2 = leaf_keys(seed, name, 0)
-    mean, amp = law(name, shape, tied)
+    mean, amp = law(name, shape, tied, extra)
     return _leaf_f32(tuple(shape), np.uint32(k1), np.uint32(k2), mean, amp,
                      jnp.dtype(served), device)
 
