@@ -37,6 +37,27 @@ class MoEConfig:
     # "ep": shard the expert dim over the model axis; "tp": shard each
     # expert's hidden dim over the model axis (used when E % tp != 0).
     partition: str = "auto"
+    # One chip's share under expert parallelism: the ids of the experts
+    # this chip holds (None: all).  The router keeps its ``num_experts``
+    # outputs and ``top_k``; the layer computes what the held experts
+    # give for the tokens routed to them and leaves out the rest.
+    held: Optional[tuple] = None
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else len(self.held)
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as Hugging Face
+    ``transformers`` computes it (``_compute_yarn_parameters``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    # scale of cos and sin; None: 0.1 * ln(factor) + 1
+    attention_factor: Optional[float] = None
 
 
 @dataclass(frozen=True)
@@ -77,6 +98,13 @@ class ModelConfig:
     rope_type: str = "rope"   # rope | mrope | none
     rope_theta: float = 10_000.0
     sliding_window: Optional[int] = None
+    # Per-layer attention kinds cycled over layers: "window" (attends
+    # the last ``sliding_window`` tokens) or "full".  None: every
+    # attention layer is a window layer where ``sliding_window`` is set.
+    attn_pattern: Optional[tuple] = None
+    # YaRN scaling of the full-attention layers' RoPE; window layers
+    # keep the default RoPE at ``rope_theta``.
+    yarn: Optional[YarnConfig] = None
     frontend: str = "token"   # token | embed_stub (audio/vlm backbones)
     norm_eps: float = 1e-5
     act: str = "silu"
@@ -112,6 +140,8 @@ class ModelConfig:
     def period(self) -> int:
         """Length of the repeating layer group (scan unit)."""
         p = _lcm(len(self.layer_pattern), len(self.ffn_pattern))
+        if self.attn_pattern is not None:
+            p = _lcm(p, len(self.attn_pattern))
         if self.num_layers % p:
             raise ValueError(
                 f"{self.name}: num_layers={self.num_layers} not divisible by "
@@ -127,6 +157,17 @@ class ModelConfig:
 
     def ffn_at(self, pos: int) -> str:
         return self.ffn_pattern[pos % len(self.ffn_pattern)]
+
+    def window_at(self, pos: int) -> Optional[int]:
+        """The attention window of layer ``pos`` (None: full)."""
+        if self.attn_pattern is None:
+            return self.sliding_window
+        kind = self.attn_pattern[pos % len(self.attn_pattern)]
+        return self.sliding_window if kind == "window" else None
+
+    def yarn_at(self, pos: int) -> Optional["YarnConfig"]:
+        """The RoPE scaling of layer ``pos``: YaRN on full layers."""
+        return None if self.window_at(pos) else self.yarn
 
     # NOTE on the ``self.__dict__`` memos below: the serving perfmodel
     # prices every simulated step through these derived scalars, and each
@@ -211,7 +252,7 @@ class ModelConfig:
             elif fn == "moe":
                 assert self.moe is not None
                 total += d * self.moe.num_experts  # router
-                total += self.moe.num_experts * 3 * d * self.moe.d_ff_expert
+                total += self.moe.num_held * 3 * d * self.moe.d_ff_expert
             total += 2 * d  # norms
         total += d  # final norm
         return total
@@ -230,10 +271,12 @@ class ModelConfig:
         total = self.param_count()
         moe_layers = sum(1 for i in range(self.num_layers)
                          if self.ffn_at(i) == "moe")
-        full = moe_layers * self.moe.num_experts * 3 * self.d_model * \
+        full = moe_layers * self.moe.num_held * 3 * self.d_model * \
             self.moe.d_ff_expert
-        active = moe_layers * self.moe.top_k * 3 * self.d_model * \
-            self.moe.d_ff_expert
+        # a held share computes top_k * held / num_experts a token
+        top = self.moe.top_k * self.moe.num_held / self.moe.num_experts
+        active = int(moe_layers * top * 3 * self.d_model *
+                     self.moe.d_ff_expert)
         return total - full + active
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
@@ -373,6 +416,7 @@ ARCH_IDS = (
     # paper's own evaluation models
     "llama3-70b",
     "mixtral-8x7b",
+    "mellum2-12b-a2.5b",
 )
 
 

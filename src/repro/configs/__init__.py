@@ -3,6 +3,7 @@ from repro.configs import (  # noqa: F401
     granite_8b,
     jamba_1_5_large_398b,
     llama3_70b,
+    mellum2_12b_a2_5b,
     minicpm_2b,
     mixtral_8x22b,
     mixtral_8x7b,

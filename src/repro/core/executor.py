@@ -303,24 +303,40 @@ def _init_params(key, *, cfg):
     return init_model(key, cfg)[0]
 
 
+def _stack_routes(routes) -> tuple:
+    """Held experts' pair counts ``{"pos<i>": (P, Eh)}`` as a 1-tuple of
+    one (L, Eh) array in layer order; () for a model with no held share,
+    whose step programs keep their three outputs."""
+    if not routes:
+        return ()
+    keys = sorted(routes, key=lambda k: int(k[3:]))
+    return (jnp.stack([routes[k] for k in keys], 1).reshape(
+        -1, routes[keys[0]].shape[-1]),)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "impl"))
 def _prefill_step(params, tokens, length, *, cfg, impl):
     """tokens (1, Lb) right-padded to a bucket; length (1,) valid tokens.
     Returns (next token (1,), its logits (1, V), the prompt's KV as a
-    one-slot cache of Lb positions)."""
+    one-slot cache of Lb positions (a window layer's ring holds the last
+    window of the valid tokens)), then, for a model that holds a share
+    of its experts, their pair counts (L, Eh)."""
     Lb = tokens.shape[1]
     pos = jnp.arange(Lb, dtype=jnp.int32)[None]
     logits, aux = forward(params, cfg, tokens, pos, impl=impl,
                           return_aux=True, last_only=True, lengths=length)
-    kv = write_prefill_to_cache(cfg, init_cache(cfg, 1, Lb), aux, Lb)
+    kv = write_prefill_to_cache(cfg, init_cache(cfg, 1, Lb), aux, Lb,
+                                lengths=length)
     logits = logits[:, 0, :cfg.vocab_size]
-    return jnp.argmax(logits, -1).astype(jnp.int32), logits, kv
+    return (jnp.argmax(logits, -1).astype(jnp.int32), logits, kv) + \
+        _stack_routes(aux.get("route"))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _insert_slot(cache, kv, row):
-    """Write a one-slot cache (leaves (P, H, 1, D, Lb)) into slot ``row``
-    of the slot cache (leaves (P, H, B, D, Sc)) in place."""
+    """Write a one-slot cache (leaves (P, H, 1, D, Lb), or a window
+    layer's ring) into slot ``row`` of the slot cache (leaves (P, H, B,
+    D, Sc)) in place."""
     return jax.tree.map(
         lambda c, x: jax.lax.dynamic_update_slice(
             c, x.astype(c.dtype), (0, 0, row, 0, 0)), cache, kv)
@@ -330,11 +346,14 @@ def _insert_slot(cache, kv, row):
                    donate_argnums=(1,))
 def _decode_step(params, cache, tokens, lens, *, cfg, impl):
     """One token for every slot: tokens (B,) at positions ``lens`` (B,).
-    Returns (next tokens (B,), logits (B, V), updated cache)."""
-    logits, cache = decode_forward(params, cfg, tokens[:, None],
-                                   lens[:, None], cache, lens, impl=impl)
+    Returns (next tokens (B,), logits (B, V), updated cache), then the
+    held experts' pair counts (L, Eh) as ``_prefill_step`` does."""
+    logits, cache, routes = decode_forward(
+        params, cfg, tokens[:, None], lens[:, None], cache, lens, impl=impl,
+        return_routing=True)
     logits = logits[:, 0, :cfg.vocab_size]
-    return jnp.argmax(logits, -1).astype(jnp.int32), logits, cache
+    return (jnp.argmax(logits, -1).astype(jnp.int32), logits, cache) + \
+        _stack_routes(routes)
 
 
 class DeviceExecutor(Executor):
@@ -348,10 +367,13 @@ class DeviceExecutor(Executor):
 
     A prefill runs each request alone, right-padded to its bucket, and
     keeps the prompt's KV on the device until the request joins the
-    decode batch; a join copies it into a free slot.  Decode runs every
-    slot each step (free slots compute and are ignored).  Sampling is
-    greedy.  Every step blocks until the device is done and reports the
-    wall time since ``execute`` was called.
+    decode batch; a join copies it into a free slot.  Layers may mix
+    full and window attention: a window layer's slot is a ring of its
+    window, written at ``position % window``.  An expert layer may hold
+    one chip's share of the experts (``MoEConfig.held``).  Decode runs
+    every slot each step (free slots compute and are ignored).  Sampling
+    is greedy.  Every step blocks until the device is done and reports
+    the wall time since ``execute`` was called.
 
     Spans (``repro.obs``): ``rapid.execute`` around each call that
     launches work, ``rapid.queue`` from a request's engine queue entry to
@@ -359,7 +381,11 @@ class DeviceExecutor(Executor):
     ``rapid.decode`` per decode step, and inside prefill and decode
     ``rapid.put`` (inputs to the device) and ``rapid.sync`` (the wait
     and the read-back of the next tokens).  Counters: ``steps``, valid
-    and padded prefill tokens, live and free decode slot-steps.
+    and padded prefill tokens, live and free decode slot-steps.  With a
+    held share of experts the routing is read back with the tokens:
+    ``rapid.prefill`` and ``rapid.decode`` carry its attributes
+    (``_routed``) and the counters ``moe_pairs_routed`` (tokens x top_k x layers) and
+    ``moe_pairs_held`` grow.
 
     Prompts are token ids drawn from ``(seed, rid)`` (``prompt_ids``);
     ``token_ids(rid)`` returns prompt plus generated ids.  With
@@ -381,12 +407,15 @@ class DeviceExecutor(Executor):
             raise ValueError(
                 f"DeviceExecutor runs the rapid scheduler only, not "
                 f"{serve.mode!r}")
-        if (cfg.frontend != "token" or cfg.sliding_window
-                or any(cfg.mixer_at(i) != "attn"
-                       for i in range(cfg.period))):
+        refused = [f"{m} mixers" for m in sorted(
+            {cfg.mixer_at(i) for i in range(cfg.period)} - {"attn"})]
+        if cfg.frontend != "token":
+            refused.append(f"the {cfg.frontend!r} frontend")
+        if refused:
             raise ValueError(
                 f"{cfg.name}: DeviceExecutor serves token-input models "
-                f"whose layers are all full attention")
+                f"whose layers are full or window attention, not "
+                f"{', '.join(refused)}")
         if serve.max_seq_len % serve.page_size:
             raise ValueError("serve.max_seq_len must be a multiple of "
                              "serve.page_size")
@@ -423,10 +452,10 @@ class DeviceExecutor(Executor):
     def _warm_up(self) -> None:
         """Compile and run every program serving will call."""
         for Lb in self.buckets:
-            _, _, kv = _prefill_step(
+            kv = _prefill_step(
                 self.params, self._put(np.zeros((1, Lb), np.int32)),
                 self._put(np.ones(1, np.int32)), cfg=self.cfg,
-                impl=self.impl)
+                impl=self.impl)[2]
             self.cache = _insert_slot(self.cache, kv, self._put(np.int32(0)))
         out = _decode_step(self.params, self.cache, self._put(self._tokens),
                            self._put(self._lens), cfg=self.cfg,
@@ -466,6 +495,19 @@ class DeviceExecutor(Executor):
         return StepOutputs(prefill=p_out, decode=d_out)
 
     # -- steps ---------------------------------------------------------------
+    def _routed(self, counts: np.ndarray, tokens: int) -> dict:
+        """Count one step's routing, its held experts' pairs (L, Eh) for
+        ``tokens`` computed, and return its span attributes:
+        ``expert_pairs`` in all, ``expert_max`` the sum over layers of
+        each layer's most loaded held expert, ``experts_live`` the
+        (layer, held expert) groups that got a pair."""
+        obs.count("moe_pairs_routed",
+                  tokens * self.cfg.moe.top_k * counts.shape[0])
+        obs.count("moe_pairs_held", int(counts.sum()))
+        return {"expert_pairs": int(counts.sum()),
+                "expert_max": int(counts.max(axis=1).sum()),
+                "experts_live": int((counts > 0).sum())}
+
     def token_ids(self, rid: int) -> List[int]:
         return list(self._ids[rid])
 
@@ -502,12 +544,14 @@ class DeviceExecutor(Executor):
                 toks[0, :n] = ids
                 toks = self._put(toks)
                 length = self._put(np.array([n], np.int32))
-            tok, logits, kv = _prefill_step(self.params, toks, length,
-                                            cfg=self.cfg, impl=self.impl)
+            tok, logits, kv, *route = _prefill_step(
+                self.params, toks, length, cfg=self.cfg, impl=self.impl)
             with obs.span("rapid.sync", worker=w):
                 jax.block_until_ready((tok, kv))
                 # read on the host: indexing the device array would compile
                 ids.append(int(np.asarray(tok)[0]))
+                if route:
+                    sp.set(**self._routed(np.asarray(route[0]), n))
         self._pending[r.rid] = (kv, n)
         if self.logits is not None:
             self.logits.setdefault(r.rid, {})[n - 1] = \
@@ -544,11 +588,12 @@ class DeviceExecutor(Executor):
         obs.count("decode_slot_steps_live", live)
         obs.count("decode_slot_steps_free", slots - live)
         w = self.worker
-        with obs.span("rapid.decode", worker=w, live=live, slots=slots):
+        with obs.span("rapid.decode", worker=w, live=live,
+                      slots=slots) as sp:
             with obs.span("rapid.put", worker=w):
                 tokens = self._put(self._tokens)
                 lens = self._put(self._lens)
-            nxt, logits, self.cache = _decode_step(
+            nxt, logits, self.cache, *route = _decode_step(
                 self.params, self.cache, tokens, lens, cfg=self.cfg,
                 impl=self.impl)
             with obs.span("rapid.sync", worker=w):
@@ -556,6 +601,8 @@ class DeviceExecutor(Executor):
                 nxt = np.asarray(nxt)
                 rec = np.asarray(logits, np.float32) \
                     if self.logits is not None else None
+                if route:
+                    sp.set(**self._routed(np.asarray(route[0]), slots))
         for i, rid in enumerate(self._row_rid):
             if rid is None:
                 continue

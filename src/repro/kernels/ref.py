@@ -104,3 +104,18 @@ def unified_pd(q_p, k_p, v_p, q_d, k_pages, v_pages, block_tables,
     o_d = paged_attention(q_d, k_pages.swapaxes(2, 3),
                           v_pages.swapaxes(2, 3), block_tables, seq_lens)
     return o_p, o_d
+
+
+def moe_gmm(xs, w_gate, w_in, w_out, group_sizes):
+    """Grouped SwiGLU: rows of group e < Eh through expert e, rows of
+    the last group zero.  xs (m, d); w_* (Eh, ...); group_sizes (Eh+1,)."""
+    Eh = w_gate.shape[0]
+    gid = jnp.repeat(jnp.arange(Eh + 1), group_sizes,
+                     total_repeat_length=xs.shape[0])
+    x = xs.astype(jnp.float32)
+    wg, wi, wo = (jnp.concatenate([w.astype(jnp.float32),
+                                   jnp.zeros_like(w[:1], jnp.float32)])[gid]
+                  for w in (w_gate, w_in, w_out))
+    h = jax.nn.silu(jnp.einsum("md,mdf->mf", x, wg)) * \
+        jnp.einsum("md,mdf->mf", x, wi)
+    return jnp.einsum("mf,mfd->md", h, wo).astype(xs.dtype)

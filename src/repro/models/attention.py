@@ -16,7 +16,9 @@ decode).  Padded heads are real compute (recorded in the roofline's
 useful-FLOPs ratio) — the logical model is unchanged.
 
 Sliding-window attention stores a ring-buffer cache of ``window`` slots so
-long-context decode reads O(window), not O(S).
+long-context decode reads O(window), not O(S).  The window and the RoPE
+scaling are per layer (``cfg.window_at(pos)``, ``cfg.yarn_at(pos)``): a
+period may mix window layers, each with its ring, and full layers.
 
 The slot cache is laid out ``(KVp, B, D, S)`` per layer, tokens on the
 last (lane) axis, and the model stacks it over layers: ``(P, KVp, B, D,
@@ -70,14 +72,16 @@ def _qkv(params, cfg, x, tp, constrain=None):
             v.reshape(B, S, kvp, D))
 
 
-def _rope(cfg, q, k, positions):
-    """positions: (B, S) for rope, (B, S, 3) for mrope."""
+def _rope(cfg, q, k, positions, pos: int = 0):
+    """positions: (B, S) for rope, (B, S, 3) for mrope; ``pos`` the
+    layer's place in the period (its RoPE scaling)."""
     if cfg.rope_type == "none":
         return q, k
     if cfg.rope_type == "mrope":
         cos, sin = mrope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
     else:
-        cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+        cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
+                                yarn=cfg.yarn_at(pos))
     return (apply_rope(q, cos, sin).astype(q.dtype),
             apply_rope(k, cos, sin).astype(k.dtype))
 
@@ -135,16 +139,18 @@ def chunked_causal_attention(q, k, v, *, chunk_q: int = 512,
 
 
 def full_attention(params, cfg, x, positions, tp, *, impl: str = "ref",
-                   constrain=None):
-    """Prefill / train path.  Returns (out, (k, v)) — k/v for cache write."""
+                   constrain=None, pos: int = 0):
+    """Prefill / train path for layer ``pos`` of the period.  Returns
+    (out, (k, v)) — k/v for cache write."""
     q, k, v = _qkv(params, cfg, x, tp, constrain)
-    q, k = _rope(cfg, q, k, positions)
+    q, k = _rope(cfg, q, k, positions, pos)
+    window = cfg.window_at(pos)
     if impl in ("pallas", "interpret"):
         from repro.kernels import ops
-        out = ops.flash_prefill(q, k, v, window=cfg.sliding_window,
+        out = ops.flash_prefill(q, k, v, window=window,
                                 interpret=impl == "interpret")
     else:
-        out = chunked_causal_attention(q, k, v, window=cfg.sliding_window)
+        out = chunked_causal_attention(q, k, v, window=window)
     B, S = x.shape[:2]
     out = out.reshape(B, S, -1)
     return jnp.einsum("bsh,hd->bsd", out, params["wo"]), (k, v)
@@ -155,28 +161,33 @@ def full_attention(params, cfg, x, positions, tp, *, impl: str = "ref",
 # ---------------------------------------------------------------------------
 
 
-def cache_shape(cfg, batch: int, max_seq: int, tp: int):
-    S = min(max_seq, cfg.sliding_window) if cfg.sliding_window else max_seq
+def cache_shape(cfg, batch: int, max_seq: int, tp: int, pos: int = 0):
+    """One layer's slot cache; a window layer keeps a ring of its
+    window."""
+    w = cfg.window_at(pos)
+    S = min(max_seq, w) if w else max_seq
     return (cfg.kv_heads_padded(tp), batch, cfg.head_dim, S)
 
 
 def decode_attention(params, cfg, x, positions, cache_k, cache_v, layer,
-                     seq_lens, tp, *, impl: str = "ref"):
-    """One-token decode step on layer ``layer`` of the stacked cache.
+                     seq_lens, tp, *, impl: str = "ref", pos: int = 0):
+    """One-token decode step on layer ``layer`` of the stacked cache of
+    the period's layer ``pos``.
 
     x (B, 1, d); positions (B, 1) or (B, 1, 3); cache_k/v
     (P, KVp, B, D, Scache), every layer's; layer: scalar int32; seq_lens
     (B,) = tokens already in cache.  Each slot's new K/V column is
     written at ``(layer, :, b, :, seq_lens[b])`` (``kv_append``, which
-    rewrites only the page that holds it) and the kernel reads the layer
-    where it lies, so the cache is neither sliced nor copied.
+    rewrites only the page that holds it; a window layer's ring at
+    ``seq_lens[b] % window``) and the kernel reads the layer where it
+    lies, so the cache is neither sliced nor copied.
     Returns (out (B,1,d), cache_k, cache_v).
     """
     B = x.shape[0]
     q, k1, v1 = _qkv(params, cfg, x, tp)
-    q, k1 = _rope(cfg, q, k1, positions)
+    q, k1 = _rope(cfg, q, k1, positions, pos)
     Scache = cache_k.shape[4]
-    w = cfg.sliding_window
+    w = cfg.window_at(pos)
     slot = (seq_lens % w) if w else seq_lens
     if impl in ("pallas", "interpret"):
         from repro.kernels import ops
@@ -214,22 +225,25 @@ def decode_attention(params, cfg, x, positions, cache_k, cache_v, layer,
 def prefill_into_cache(cache_k, cache_v, k, v, seq_lens=None, window=None):
     """Write a full prompt's K/V into the slot cache (left-aligned).
 
-    cache_k/v (KVp, B, D, Sc); k/v (B, S, KVp, D).  With a ring-buffer
-    (window) cache only the last ``window`` tokens are kept, at their
-    rotated slots.
+    cache_k/v (KVp, B, D, Sc); k/v (B, S, KVp, D).  A ring-buffer
+    (window) cache keeps only the last ``window`` tokens, at their
+    rotated slots: of all S, or with ``seq_lens`` (B,) of each row's
+    valid tokens (rows right-padded to S).
     """
-    S = k.shape[1]
+    B, S = k.shape[:2]
     k = k.transpose(2, 0, 3, 1)          # (KVp, B, D, S)
     v = v.transpose(2, 0, 3, 1)
     if window:
+        # ring slot s holds the last valid position p < n with p % W == s
+        n = (jnp.full((B,), S, jnp.int32) if seq_lens is None
+             else seq_lens)[:, None]
         W = cache_k.shape[3]
-        take = min(S, W)
-        src_pos = jnp.arange(take) + max(S - W, 0)
-        slots = src_pos % W
-        cache_k = cache_k.at[..., slots].set(
-            k[..., max(S - W, 0):].astype(cache_k.dtype))
-        cache_v = cache_v.at[..., slots].set(
-            v[..., max(S - W, 0):].astype(cache_v.dtype))
+        p = n - 1 - (n - 1 - jnp.arange(W)) % W                 # (B, W)
+        ok = (p >= 0)[None, :, None, :]
+        take = lambda x: jnp.take_along_axis(
+            x, jnp.clip(p, 0, S - 1)[None, :, None, :], axis=3)
+        cache_k = jnp.where(ok, take(k), 0).astype(cache_k.dtype)
+        cache_v = jnp.where(ok, take(v), 0).astype(cache_v.dtype)
     else:
         cache_k = jax.lax.dynamic_update_slice(
             cache_k, k.astype(cache_k.dtype), (0, 0, 0, 0))

@@ -105,12 +105,48 @@ def act_fn(name: str):
 # ---------------------------------------------------------------------------
 
 
-def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32):
-    """positions (..., S) -> cos/sin (..., S, head_dim//2)."""
+def rope_cos_sin(positions, head_dim: int, theta: float, dtype=jnp.float32,
+                 yarn=None):
+    """positions (..., S) -> cos/sin (..., S, head_dim//2).  ``yarn`` (a
+    ``YarnConfig``) rescales the frequencies and cos and sin."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    scale = 1.0
+    if yarn is not None:
+        freqs, scale = yarn_parameters(head_dim, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs
-    return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+    return ((jnp.cos(ang) * scale).astype(dtype),
+            (jnp.sin(ang) * scale).astype(dtype))
+
+
+def yarn_parameters(head_dim: int, theta: float, yarn):
+    """YaRN's inverse frequencies (head_dim // 2,) and cos/sin scale.
+
+    Each frequency blends its extrapolated value (unchanged) with its
+    interpolated one (divided by ``factor``) by a linear ramp between the
+    dims whose wavelength turns ``beta_fast`` and ``beta_slow`` times
+    over the original context: dims below the ramp keep their
+    frequency, dims above it are interpolated.
+    """
+    def correction_dim(rotations):
+        return (head_dim * math.log(
+            yarn.original_max_position / (rotations * 2 * math.pi))) / \
+            (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    half = head_dim // 2
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                            / head_dim))
+    inv = base / yarn.factor * ramp + base * (1.0 - ramp)
+    scale = yarn.attention_factor
+    if scale is None:
+        scale = 0.1 * math.log(yarn.factor) + 1.0
+    return inv, scale
 
 
 def apply_rope(x, cos, sin):

@@ -9,6 +9,11 @@ Expert partitioning:
     GSPMD materializes the token all-to-all at the dispatch einsum.
   * ``tp`` (else, e.g. Mixtral 8e over 16 chips): every expert's hidden dim
     sharded over the model axis (pure tensor parallelism).
+
+A configuration that holds one chip's share of the experts
+(``MoEConfig.held``) runs ``held_moe_ffn`` instead: the router spans
+every expert, the (token, choice) pairs that fall on held experts are
+computed without capacity or drops, and the absent experts add nothing.
 """
 from __future__ import annotations
 
@@ -16,6 +21,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import ParamBuilder, act_fn
 
@@ -33,6 +39,7 @@ def moe_partition(cfg, tp: int) -> str:
 
 
 def init_moe(b: ParamBuilder, cfg, tp: int):
+    """Router over all ``num_experts``; expert banks of the held ones."""
     m = cfg.moe
     d, E, f = cfg.d_model, m.num_experts, m.d_ff_expert
     if moe_partition(cfg, tp) == "ep":
@@ -42,9 +49,65 @@ def init_moe(b: ParamBuilder, cfg, tp: int):
         w_spec_in = (None, "data", "model")
         w_spec_out = (None, "model", "data")
     b.param("router", (d, E), (None, None))
-    b.param("w_gate", (E, d, f), w_spec_in)
-    b.param("w_in", (E, d, f), w_spec_in)
-    b.param("w_out", (E, f, d), w_spec_out)
+    Eh = m.num_held
+    b.param("w_gate", (Eh, d, f), w_spec_in)
+    b.param("w_in", (Eh, d, f), w_spec_in)
+    b.param("w_out", (Eh, f, d), w_spec_out)
+
+
+def held_moe_ffn(params, cfg, x, *, impl: str = "ref", valid=None):
+    """This chip's share of an expert layer.  x (B, S, d); ``valid``
+    (B, S) marks the tokens to compute (None: all).  Returns
+    (y (B, S, d), tokens per held expert (num_held,) int32).
+
+    Each token is routed over all ``num_experts`` (softmax, top-k, the
+    k gates renormalised to sum to one); of its k (token, choice) pairs
+    those on held experts are computed and weighted by their gates.
+    With ``impl`` ``pallas`` / ``interpret`` the pairs are sorted by
+    held expert and run through the grouped SwiGLU kernel
+    (``kernels.moe_gmm``), which touches no weight of an expert that
+    got no pair; ``ref`` computes every held expert on every token and
+    weights the unrouted ones by zero (XLA-native, differentiable).
+    """
+    m = cfg.moe
+    B, S, d = x.shape
+    T, k, Eh = B * S, m.top_k, m.num_held
+    held = tuple(range(m.num_experts)) if m.held is None else m.held
+    xt = x.reshape(T, d)
+    logits = jnp.einsum("td,de->te", xt, params["router"],
+                        preferred_element_type=jnp.float32)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    gate = gate / gate.sum(-1, keepdims=True)                 # (T, k)
+    # local index of each choice's expert; Eh for one held elsewhere
+    local = np.full((m.num_experts,), Eh, np.int32)
+    local[list(held)] = np.arange(Eh)
+    e = jnp.asarray(local)[idx]
+    if valid is not None:
+        e = jnp.where(valid.reshape(T, 1), e, Eh)
+    # pairs per group (one-hot sums: the TPU compiler refuses some
+    # scatters inside the layer scan)
+    counts = (e.reshape(-1, 1) == jnp.arange(Eh + 1)).sum(0, jnp.int32)
+    if impl in ("pallas", "interpret"):
+        from repro.kernels import moe_gmm
+        flat = e.reshape(-1)
+        order = jnp.argsort(flat, stable=True)      # by expert, others last
+        h = moe_gmm.moe_gmm(xt[order // k], params["w_gate"],
+                            params["w_in"], params["w_out"], counts,
+                            act=act_fn(cfg.act),
+                            interpret=impl == "interpret")
+        # back to (token, choice) order; pairs held elsewhere are zero
+        h = h[jnp.argsort(order)].reshape(T, k, d)
+        y = jnp.einsum("tkd,tk->td", h, gate.astype(h.dtype),
+                       preferred_element_type=jnp.float32)
+    else:
+        act = act_fn(cfg.act)
+        w = jnp.einsum("tk,tke->te", gate,
+                       (e[..., None] == jnp.arange(Eh)).astype(jnp.float32))
+        h = act(jnp.einsum("td,edf->tef", xt, params["w_gate"])) * \
+            jnp.einsum("td,edf->tef", xt, params["w_in"])
+        y = jnp.einsum("tef,efd,te->td", h, params["w_out"],
+                       w.astype(h.dtype), preferred_element_type=jnp.float32)
+    return y.reshape(B, S, d).astype(x.dtype), counts[:Eh]
 
 
 def moe_ffn(params, cfg, x, *, group_size: int = 256, constrain=None,

@@ -19,6 +19,11 @@ Two entry points:
                          population — the serving prefill path.
   * ``decode_forward`` — one-token step against per-layer caches/states.
 
+A layer's attention window and RoPE scaling follow its place in the
+period (``cfg.window_at``, ``cfg.yarn_at``); an expert layer that holds
+one chip's share (``MoEConfig.held``) also reports the pairs each held
+expert computed (``aux["route"]``, ``decode_forward(return_routing=)``).
+
 ``inputs`` is either int32 tokens (B, S) or, for ``frontend='embed_stub'``
 archs (audio/VLM backbones), precomputed float embeddings (B, S, d_model).
 """
@@ -132,7 +137,10 @@ def _embed_inputs(params, cfg, inputs, constrain: Constrain):
 
 
 def _block_forward(p, cfg, pos, x, positions, tp, impl, constrain,
-                   collect_aux: bool):
+                   collect_aux: bool, valid=None):
+    """One block.  Returns (x, aux, route): route is the held experts'
+    pair counts (None unless the layer holds a share of its experts);
+    ``valid`` (B, S) marks the tokens a held-expert layer computes."""
     mixer = cfg.mixer_at(pos)
     h = rmsnorm(x, p["norm1"]["w"], cfg.norm_eps)
     # Megatron-SP boundary: gather S at block entry so the block computes
@@ -144,7 +152,7 @@ def _block_forward(p, cfg, pos, x, positions, tp, impl, constrain,
     if mixer == "attn":
         out, (k, v) = attn_mod.full_attention(
             p["mixer"], cfg, h, positions, tp, impl=impl,
-            constrain=constrain)
+            constrain=constrain, pos=pos)
         if collect_aux:
             aux = {"k": k, "v": v}
     elif mixer == "mamba":
@@ -164,11 +172,15 @@ def _block_forward(p, cfg, pos, x, positions, tp, impl, constrain,
         if collect_aux:
             aux = state
     x = x + out
+    route = None
     if cfg.ffn_at(pos) != "none":
         h = rmsnorm(x, p["norm2"]["w"], cfg.norm_eps)
         h = constrain(h, ("batch", None, None))   # SP gather (see above)
         if cfg.ffn_at(pos) == "dense":
             y = moe_mod.dense_ffn(p["ffn"], cfg, h, constrain=constrain)
+        elif cfg.moe.held is not None:
+            y, route = moe_mod.held_moe_ffn(p["ffn"], cfg, h, impl=impl,
+                                            valid=valid)
         else:
             # collect_aux == the serving-prefill path -> inference
             # capacity policy (generation must not drop tokens)
@@ -180,7 +192,7 @@ def _block_forward(p, cfg, pos, x, positions, tp, impl, constrain,
     # rules) so the per-period remat checkpoints are TP-sharded instead
     # of replicated — 16x smaller saved activations (see §Perf log).
     x = constrain(x, ("batch", "seq", None))
-    return x, aux
+    return x, aux, route
 
 
 def forward(params, cfg, inputs, positions, tp: int = 1, *,
@@ -193,9 +205,13 @@ def forward(params, cfg, inputs, positions, tp: int = 1, *,
     ``last_only`` computes the LM head on the final position only (the
     serving prefill path — full 32K-position logits would be ~100s of GB);
     for rows right-padded to a bucket, ``lengths`` (B,) gives each row's
-    valid tokens and the head reads position ``lengths - 1`` instead.
+    valid tokens and the head reads position ``lengths - 1`` instead,
+    and a held-expert layer computes only the valid tokens.
     """
     x = _embed_inputs(params, cfg, inputs, constrain)
+    valid = None
+    if lengths is not None:
+        valid = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
 
     # Per-LAYER remat (not per-period): inside a period's backward every
     # position's weight-gradient is live simultaneously; for jamba's
@@ -209,12 +225,17 @@ def forward(params, cfg, inputs, positions, tp: int = 1, *,
 
     def period_body(x, layer_p):
         layer_p, x = grad_barrier((layer_p, x))
-        auxes = {}
+        auxes, routes = {}, {}
         for pos in range(cfg.period):
-            x, aux = block(layer_p[f"pos{pos}"], cfg, pos, x,
-                           positions, tp, impl, constrain, return_aux)
+            x, aux, route = block(layer_p[f"pos{pos}"], cfg, pos, x,
+                                  positions, tp, impl, constrain,
+                                  return_aux, valid)
             if return_aux:
                 auxes[f"pos{pos}"] = aux
+            if route is not None:
+                routes[f"pos{pos}"] = route
+        if routes:
+            auxes["route"] = routes
         return x, (auxes if return_aux else None)
 
     x, aux = jax.lax.scan(period_body, x, params["layers"])
@@ -254,8 +275,8 @@ def lm_loss(params, cfg, tokens_or_embeds, labels, positions, tp: int = 1, *,
         # stacked weights out of the scan (§Perf log; no-op on TPU)
         layer_p, x = grad_barrier((layer_p, x))
         for pos in range(cfg.period):
-            x, _ = block(layer_p[f"pos{pos}"], cfg, pos, x, positions,
-                         tp, impl, constrain, False)
+            x, _, _ = block(layer_p[f"pos{pos}"], cfg, pos, x, positions,
+                            tp, impl, constrain, False)
         return x, None
 
     x, _ = jax.lax.scan(period_body, x, params["layers"])
@@ -299,7 +320,7 @@ def init_cache(cfg, batch: int, max_seq: int, tp: int = 1,
     for pos in range(cfg.period):
         mixer = cfg.mixer_at(pos)
         if mixer == "attn":
-            shape = attn_mod.cache_shape(cfg, batch, max_seq, tp)
+            shape = attn_mod.cache_shape(cfg, batch, max_seq, tp, pos)
             per_pos[f"pos{pos}"] = {"k": jnp.zeros(shape, dtype),
                                     "v": jnp.zeros(shape, dtype)}
         elif mixer == "mamba":
@@ -343,10 +364,12 @@ def cache_specs(cfg, tp: int = 1):
     return per_pos
 
 
-def write_prefill_to_cache(cfg, cache, aux, seq_len: int):
+def write_prefill_to_cache(cfg, cache, aux, seq_len: int, lengths=None):
     """Populate a fresh cache tree from ``forward(return_aux=True)`` aux.
 
-    attn: K/V written left-aligned (ring-rotated under sliding window);
+    attn: K/V written left-aligned (a window layer's ring holds the last
+    window tokens at their rotated slots: of all ``seq_len``, or with
+    ``lengths`` (B,) of each right-padded row's valid tokens);
     recurrent mixers: final state replaces the zero state.
     """
     out = {}
@@ -354,10 +377,9 @@ def write_prefill_to_cache(cfg, cache, aux, seq_len: int):
         key = f"pos{pos}"
         mixer = cfg.mixer_at(pos)
         if mixer == "attn":
-            out[key] = {"k": _write_kv(cache[key]["k"], aux[key]["k"],
-                                       cfg.sliding_window),
-                        "v": _write_kv(cache[key]["v"], aux[key]["v"],
-                                       cfg.sliding_window)}
+            w = cfg.window_at(pos)
+            out[key] = {n: _write_kv(cache[key][n], aux[key][n], w, lengths)
+                        for n in ("k", "v")}
         else:
             out[key] = jax.tree.map(
                 lambda c, s: s.astype(c.dtype).reshape(c.shape),
@@ -365,21 +387,24 @@ def write_prefill_to_cache(cfg, cache, aux, seq_len: int):
     return out
 
 
-def _write_kv(cache, kv, window):
+def _write_kv(cache, kv, window, lengths=None):
     """cache (P,H,B,D,Sc); kv (P,B,S,H,D)."""
-    P = cache.shape[0]
     def one(c, x):
-        ck, _ = attn_mod.prefill_into_cache(c, c, x, x, window=window)
+        ck, _ = attn_mod.prefill_into_cache(c, c, x, x, seq_lens=lengths,
+                                            window=window)
         return ck
     return jax.vmap(one)(cache, kv)
 
 
 def decode_forward(params, cfg, inputs, positions, cache, seq_lens,
                    tp: int = 1, *, impl: str = "ref",
-                   constrain: Constrain = _IDENTITY):
+                   constrain: Constrain = _IDENTITY,
+                   return_routing: bool = False):
     """One-token decode.  inputs (B,1) tokens or (B,1,d) embeds;
     positions (B,1) or (B,1,3); seq_lens (B,) tokens already cached.
-    Returns (logits (B,1,vocab_padded), new_cache).
+    Returns (logits (B,1,vocab_padded), new_cache), and with
+    ``return_routing`` the held experts' pair counts per period and
+    layer (``{"pos<i>": (P, num_held)}``, empty without a held share).
     """
     x = _embed_inputs(params, cfg, inputs, constrain)
 
@@ -394,6 +419,7 @@ def decode_forward(params, cfg, inputs, positions, cache, seq_lens,
         layer_p, idx = scanned
         layer_p, x = grad_barrier((layer_p, x))
         cache = dict(cache)
+        routes = {}
         for pos in range(cfg.period):
             key = f"pos{pos}"
             p = layer_p[key]
@@ -402,7 +428,7 @@ def decode_forward(params, cfg, inputs, positions, cache, seq_lens,
             if mixer == "attn":
                 out, ck, cv = attn_mod.decode_attention(
                     p["mixer"], cfg, h, positions, cache[key]["k"],
-                    cache[key]["v"], idx, seq_lens, tp, impl=impl)
+                    cache[key]["v"], idx, seq_lens, tp, impl=impl, pos=pos)
                 cache[key] = {"k": ck, "v": cv}
             else:
                 step = {"mamba": mamba_mod.mamba_decode_step,
@@ -421,17 +447,22 @@ def decode_forward(params, cfg, inputs, positions, cache, seq_lens,
                 if cfg.ffn_at(pos) == "dense":
                     y = moe_mod.dense_ffn(p["ffn"], cfg, h,
                                           constrain=constrain)
+                elif cfg.moe.held is not None:
+                    y, routes[key] = moe_mod.held_moe_ffn(
+                        p["ffn"], cfg, h, impl=impl)
                 else:
                     y = moe_mod.moe_ffn(p["ffn"], cfg, h,
                                         constrain=constrain, dropless=True)
                 x = x + y
-        return (x, cache), None
+        return (x, cache), routes
 
     P_ = cfg.num_periods
-    (x, new_cache), _ = jax.lax.scan(
+    (x, new_cache), routes = jax.lax.scan(
         period_body, (x, cache),
         (params["layers"], jnp.arange(P_, dtype=jnp.int32)))
     logits = lm_logits(params, cfg, x)
+    if return_routing:
+        return logits, new_cache, routes
     return logits, new_cache
 
 

@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.config import get_config
 from repro.core import executor as X
 from repro.kernels.flash_prefill import flash_prefill
-from repro.kernels.ops import DENSE_PAGE
+from repro.kernels.moe_gmm import moe_gmm
+from repro.kernels.ops import DENSE_PAGE, paged_attention_dense
 from repro.kernels.paged_attention import kv_append, paged_attention
 from repro.kernels.ssm_scan import ssm_scan
 from repro.kernels.unified_pd import unified_pd
@@ -29,7 +30,8 @@ from repro.models.transformer import init_cache
 
 PAGE = 16           # unified_pd's (Hkv, N, page, D) pages
 # the benchmark's configurations at their serving shapes: (slots, slot len)
-SERVED = {"starcoder2-3b": (64, 2048), "minicpm-2b": (8, 2048)}
+SERVED = {"starcoder2-3b": (64, 2048), "minicpm-2b": (8, 2048),
+          "mellum2-12b-a2.5b": (32, 8192)}
 
 
 @pytest.fixture(scope="module")
@@ -111,6 +113,38 @@ def test_kv_append_compiles(one_chip, arch):
     assert _has_kernel(c)
 
 
+@pytest.mark.parametrize("tokens", [32, 4096])
+def test_moe_gmm_compiles(one_chip, tokens):
+    """The held-expert SwiGLU at Mellum2's widths (2304 x 896, 16 of 64
+    experts, top-8): a decode step of 32 slots and a 4096-token
+    prefill."""
+    cfg = get_config("mellum2-12b-a2.5b")
+    d, f, Eh = cfg.d_model, cfg.moe.d_ff_expert, cfg.moe.num_held
+    xs = _sds((tokens * cfg.moe.top_k, d), jnp.bfloat16, one_chip)
+    w = _sds((Eh, d, f), jnp.bfloat16, one_chip)
+    wo = _sds((Eh, f, d), jnp.bfloat16, one_chip)
+    gs = _sds((Eh + 1,), jnp.int32, one_chip)
+    c = moe_gmm.lower(xs, w, w, wo, gs, interpret=False).compile()
+    assert _has_kernel(c)
+
+
+def test_window_ring_decode_kernels_compile(one_chip):
+    """``kv_append`` and ``paged_attention`` over Mellum2's window rings:
+    a stacked (7, 4, 32, 128, 1024) cache at the serving shape."""
+    cfg = get_config("mellum2-12b-a2.5b")
+    B, Hkv, D, W = 32, cfg.num_kv_heads, cfg.head_dim, cfg.sliding_window
+    ring = _sds((7, Hkv, B, D, W), jnp.bfloat16, one_chip)
+    new = _sds((B, Hkv, D), jnp.bfloat16, one_chip)
+    lens = _sds((B,), jnp.int32, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    c = kv_append.lower(ring, ring, new, new, lens, layer, page=DENSE_PAGE,
+                        interpret=False).compile()
+    assert _has_kernel(c)
+    q = _sds((B, cfg.num_heads, D), jnp.bfloat16, one_chip)
+    read = jax.jit(functools.partial(paged_attention_dense, window=W))
+    assert _has_kernel(read.lower(q, ring, ring, layer, lens).compile())
+
+
 def test_unified_pd_compiles(one_chip, sc2):
     D, Sp = sc2.head_dim, 512
     q_p = _sds((1, sc2.num_heads, Sp, D), jnp.bfloat16, one_chip)
@@ -173,10 +207,11 @@ def _slab_traffic(hlo: str, slab, cache):
 @pytest.mark.parametrize("arch", sorted(SERVED))
 def test_decode_step_updates_cache_in_place(one_chip, arch):
     """The served decode program at the benchmark's widths and slot shape
-    (2 layers) writes each new K/V column into the stacked cache and the
-    kernel reads it there: no layer's slab is sliced out, relaid, copied
-    or written back."""
-    cfg = dataclasses.replace(get_config(arch), num_layers=2)
+    (two periods of layers) writes each new K/V column into the stacked
+    cache and the kernel reads it there: no layer's slab, full or window
+    ring, is sliced out, relaid, copied or written back."""
+    base = get_config(arch)
+    cfg = dataclasses.replace(base, num_layers=2 * base.period)
     B, S = SERVED[arch]
 
     def on_chip(tree):
@@ -189,9 +224,12 @@ def test_decode_step_updates_cache_in_place(one_chip, arch):
     hlo = X._decode_step.lower(params, cache, ints, ints, cfg=cfg,
                                impl="pallas").compile().as_text()
     assert "paged_attention" in hlo and "kv_append" in hlo
-    k = cache["pos0"]["k"]
-    assert k.shape == (2, cfg.num_kv_heads, B, cfg.head_dim, S)
-    assert _slab_traffic(hlo, k.shape[1:], k.shape) == []
+    for pos in range(cfg.period):
+        # a window layer's slot is a ring of its window
+        k = cache[f"pos{pos}"]["k"]
+        assert k.shape == (2, cfg.num_kv_heads, B, cfg.head_dim,
+                           min(S, cfg.window_at(pos) or S))
+        assert _slab_traffic(hlo, k.shape[1:], k.shape) == []
 
 
 def test_ssm_scan_compiles(one_chip):

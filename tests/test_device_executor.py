@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.config import ServeConfig, get_reduced_config
 from repro.core import DeviceExecutor, Request, State, drive, make_engine
 from repro.core.executor import prefill_buckets, prompt_ids
@@ -138,6 +139,54 @@ def test_slot_bounds_admission(cfg, serve, cpu):
 def test_refuses_cpu_without_interpret(cfg, serve, cpu):
     with pytest.raises(RuntimeError, match="TPU"):
         DeviceExecutor(cfg, serve, cpu)
+
+
+def test_refuses_mixers_other_than_attention(serve, cpu):
+    """Window and full attention are served; recurrent mixers are not."""
+    for arch, kind in (("jamba-1.5-large-398b", "mamba"),
+                       ("xlstm-125m", "lstm")):
+        with pytest.raises(ValueError, match=kind):
+            DeviceExecutor(get_reduced_config(arch), serve, cpu,
+                           interpret=True)
+
+
+def test_mellum_share_serves_past_the_window_and_matches_reference(cpu):
+    """Reduced Mellum2 (two periods of three window-32 layers and a full
+    one; 8 experts top-2, this chip holding 4) through the Engine:
+    prompts beyond the window fill its rings, decodes wrap them, and
+    every served logit matches the f32 forward of the same share.  The
+    preset is float32 (a top-2 choice among 8 experts at this width
+    flips on bfloat16 rounding), so only summation order differs: 1e-3
+    of a logit's spread.  The routing read back with each step is in
+    its span and the counters."""
+    cfg = get_reduced_config("mellum2-12b-a2.5b")
+    assert cfg.dtype == "float32"
+    serve = ServeConfig(mode="rapid", chips=1, max_batch_slots=3,
+                        max_seq_len=128, page_size=16)
+    obs.reset()
+    ex = _executor(cfg, serve, cpu)
+    eng = make_engine("rapid", cfg, serve, executor=ex)
+    reqs = _requests([(40, 6), (20, 30), (70, 9)])
+    drive(eng, reqs)
+    for r in reqs:
+        assert r.state is State.FINISHED, r
+        assert _max_logit_error(ex, cfg, r.rid) < 1e-3
+    assert max(r.prompt_len for r in reqs) > 2 * cfg.sliding_window
+    assert reqs[1].prompt_len + reqs[1].max_new_tokens > \
+        cfg.sliding_window
+    k = cfg.moe.top_k * cfg.num_layers
+    done = [s for s in obs.spans() if s.name in ("rapid.prefill",
+                                                   "rapid.decode")]
+    assert done and all(s.attrs["expert_pairs"] >= s.attrs["expert_max"]
+                        > 0 for s in done)
+    assert all(0 < s.attrs["experts_live"] <= cfg.num_layers *
+               cfg.moe.num_held for s in done)
+    routed = sum(s.attrs.get("valid", s.attrs.get("slots", 0)) * k
+                 for s in done)
+    held = sum(s.attrs["expert_pairs"] for s in done)
+    assert obs.counter("moe_pairs_routed") == routed
+    assert obs.counter("moe_pairs_held") == held
+    assert 0 < held < routed
 
 
 def test_refuses_other_schedulers(cfg, serve, cpu):

@@ -237,3 +237,36 @@ def test_kv_append(rng, L, layer, Hkv, B, D, S, page):
     want_v = vc.at[layer, :, b, :, at].set(vn.astype(vc.dtype))
     np.testing.assert_array_equal(ko, want_k)
     np.testing.assert_array_equal(vo, want_v)
+
+
+# ---------------------------------------------------------------------------
+# moe_gmm — grouped SwiGLU over the held experts
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("sizes,d,f", [
+    ((5, 0, 1, 20, 6), 64, 32),        # an empty and a one-row expert
+    ((0, 0, 0, 1, 31), 32, 16),        # one pair held, the rest elsewhere
+    ((8, 8, 8, 8, 0), 128, 64),        # every row held
+    ((0, 0, 0, 0, 16), 32, 16),        # nothing held: all rows zero
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm(rng, sizes, d, f, dtype):
+    """Rows of held expert e through expert e's SwiGLU, the last group's
+    rows (experts on other chips) zero, held experts with no row or one
+    row included."""
+    from repro.kernels.moe_gmm import moe_gmm
+    Eh = len(sizes) - 1
+    ks = jax.random.split(rng, 4)
+    m = sum(sizes)
+    xs = _rand(ks[0], (m, d), dtype)
+    wg = _rand(ks[1], (Eh, d, f), dtype) / d ** 0.5
+    wi = _rand(ks[2], (Eh, d, f), dtype) / d ** 0.5
+    wo = _rand(ks[3], (Eh, f, d), dtype) / f ** 0.5
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = moe_gmm(xs, wg, wi, wo, gs, interpret=True)
+    want = ref.moe_gmm(xs, wg, wi, wo, gs)
+    assert got.shape == (m, d) and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **TOL[dtype])
+    np.testing.assert_array_equal(np.asarray(got[m - sizes[-1]:]), 0)

@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.config import get_reduced_config, list_archs
+from repro.config import get_config, get_reduced_config, list_archs
+from repro.models import moe as moe_mod
+from repro.models.layers import rope_cos_sin, yarn_parameters
 from repro.models.transformer import (decode_forward, forward, greedy_sample,
                                       init_cache, init_model, lm_loss,
                                       write_prefill_to_cache)
@@ -137,6 +139,12 @@ def test_sliding_window_ring_cache(rng):
     logits, aux = forward(params, cfg, x, pos, 1, return_aux=True)
     cache = init_cache(cfg, B, 64, 1)
     assert cache["pos0"]["k"].shape[4] == cfg.sliding_window
+    # per kind: a period of window layers and a full one keeps a ring
+    # where the layer has a window and the whole slot where it has none
+    mixed = get_reduced_config("mellum2-12b-a2.5b")
+    kinds = init_cache(mixed, B, 64, 1)
+    assert [kinds[f"pos{p}"]["k"].shape[4] for p in range(4)] == \
+        [mixed.sliding_window] * 3 + [64]
     cache = write_prefill_to_cache(cfg, cache, aux, S)
     nxt = greedy_sample(logits[:, -1:], cfg.vocab_size)
     dl, _ = decode_forward(params, cfg, nxt,
@@ -226,3 +234,110 @@ def test_decode_writes_each_slot_column_in_place(rng):
     for name in ("k", "v"):
         a, b_ = cache["pos0"][name], before["pos0"][name]
         assert bool(jnp.all(jnp.where(keep, a == b_, True))), name
+
+
+def test_yarn_frequencies_and_scale():
+    """Mellum2's full layers: YaRN as written out here.  Correction dims
+    of beta_fast 32 and beta_slow 1 rotations over 8192 positions (18
+    and 35 at head dim 128, theta 500000); a linear ramp between them
+    over the 64 frequencies blends theta**(-2i/D) (below the ramp) into
+    it divided by the factor 16 (above); cos and sin scaled by the
+    published attention factor."""
+    cfg = get_config("mellum2-12b-a2.5b")
+    y, D, theta = cfg.yarn, cfg.head_dim, cfg.rope_theta
+    assert cfg.yarn_at(3) is y and cfg.yarn_at(0) is None
+
+    def dim(rot):
+        return D * np.log(8192 / (rot * 2 * np.pi)) / (2 * np.log(theta))
+    low, high = int(np.floor(dim(32))), int(np.ceil(dim(1)))
+    assert (low, high) == (18, 35)
+    base = theta ** (-np.arange(0, D, 2) / D)
+    ramp = np.clip((np.arange(D // 2) - low) / (high - low), 0, 1)
+    want = base / 16 * ramp + base * (1 - ramp)
+    inv, scale = yarn_parameters(D, theta, y)
+    np.testing.assert_allclose(np.asarray(inv), want, rtol=1e-6)
+    assert scale == pytest.approx(0.1 * np.log(16) + 1, rel=1e-6)
+    # small positions: a float32 angle at thousands of radians is off
+    # by ~1e-3 of its own rounding, which checks nothing of YaRN
+    pos = jnp.array([[0, 1, 2, 100]])
+    cos, sin = rope_cos_sin(pos, D, theta, yarn=y)
+    ang = np.asarray(pos)[..., None] * want
+    np.testing.assert_allclose(np.asarray(cos), np.cos(ang) * scale,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(sin), np.sin(ang) * scale,
+                               atol=2e-5)
+    # window layers keep the default RoPE
+    c0, _ = rope_cos_sin(pos, D, theta)
+    np.testing.assert_allclose(np.asarray(c0), np.cos(
+        np.asarray(pos)[..., None] * base), atol=2e-5)
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+def test_held_expert_shares_sum_to_the_uncut_layer(rng, shares, impl):
+    """Each chip's share of an 8-expert top-2 layer, computed by the
+    held-expert layer with its slice of the banks, sums over the shares
+    to the whole layer (every expert held, no token dropped).  float32,
+    so only summation order differs: 1e-5."""
+    base = dataclasses.replace(get_reduced_config("mellum2-12b-a2.5b"),
+                               dtype="float32")
+    E = base.moe.num_experts
+    whole = dataclasses.replace(base, moe=dataclasses.replace(
+        base.moe, held=None))
+    params, _ = init_model(rng, whole, tp=1)
+    p = jax.tree.map(lambda a: a[0], params["layers"]["pos0"]["ffn"])
+    x = jax.random.normal(jax.random.fold_in(rng, 7), (2, 24, base.d_model))
+    want = moe_mod.moe_ffn(p, whole, x, dropless=True)
+    got = 0
+    pairs = 0
+    for c in range(shares):
+        held = tuple(range(c * E // shares, (c + 1) * E // shares))
+        cfg = dataclasses.replace(base, moe=dataclasses.replace(
+            base.moe, held=held))
+        mine = dict(p, **{k: p[k][jnp.array(held)]
+                          for k in ("w_gate", "w_in", "w_out")})
+        y, counts = moe_mod.held_moe_ffn(mine, cfg, x, impl=impl)
+        got = got + y
+        pairs += int(counts.sum())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    assert pairs == 2 * 24 * base.moe.top_k       # each pair on one chip
+
+
+def test_window_rings_from_padded_prompts_then_decode_past_the_window(rng):
+    """A period of three window layers and a full one: prompts
+    right-padded to a bucket fill each window ring from their own last
+    tokens (``lengths``), and decode steps past the window (the ring
+    wraps) match the full forward.  float32 on both sides: 1e-4."""
+    cfg = dataclasses.replace(get_reduced_config("mellum2-12b-a2.5b"),
+                              dtype="float32")
+    W = cfg.sliding_window
+    params, _ = init_model(rng, cfg, tp=1)
+    lens, Lb, T = [45, 20], 64, 20       # one beyond the window, one not
+    B = len(lens)
+    seqs = jax.random.randint(jax.random.fold_in(rng, 3), (B, Lb + T), 0,
+                              cfg.vocab_size)
+    padded = jnp.where(jnp.arange(Lb)[None] < jnp.array(lens)[:, None],
+                       seqs[:, :Lb], 0)
+    pos = jnp.broadcast_to(jnp.arange(Lb)[None], (B, Lb))
+    _, aux = forward(params, cfg, padded, pos, 1, impl="interpret",
+                     return_aux=True, lengths=jnp.array(lens))
+    one = write_prefill_to_cache(cfg, init_cache(cfg, B, Lb, 1), aux, Lb,
+                                 lengths=jnp.array(lens))
+    cache = init_cache(cfg, B, Lb + T, 1)
+    cache = jax.tree.map(lambda c, x: c.at[..., :x.shape[-1]].set(x),
+                         cache, one)
+    # causal: position n of the whole sequence is what decode sees at n
+    full = forward(params, cfg, seqs, jnp.broadcast_to(
+        jnp.arange(Lb + T)[None], (B, Lb + T)), 1)
+    step = jax.jit(decode_forward, static_argnames=("cfg", "tp", "impl"))
+    cur = jnp.array(lens, jnp.int32)
+    for t in range(T):
+        tok = jnp.stack([seqs[b, lens[b] + t] for b in range(B)])[:, None]
+        dl, cache = step(params, cfg, tok, cur[:, None], cache, cur, tp=1,
+                         impl="interpret")
+        want = jnp.stack([full[b, lens[b] + t] for b in range(B)])
+        np.testing.assert_allclose(np.asarray(dl[:, 0]), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+        cur = cur + 1
+    assert max(lens) + T > 2 * W and min(lens) < W
