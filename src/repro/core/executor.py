@@ -28,6 +28,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import obs
 from repro.core.scheduler import SchedView, StepPlan
+from repro.kernels import ops
 from repro.perfmodel import batch as B
 from repro.perfmodel import costs as C
 from repro.perfmodel import interference as I
@@ -381,7 +382,9 @@ class DeviceExecutor(Executor):
     ``rapid.decode`` per decode step, and inside prefill and decode
     ``rapid.put`` (inputs to the device) and ``rapid.sync`` (the wait
     and the read-back of the next tokens).  Counters: ``steps``, valid
-    and padded prefill tokens, live and free decode slot-steps.  With a
+    and padded prefill tokens, live and free decode slot-steps, and the
+    KV blocks decode attention fetches and spans (``ops.dense_blocks``;
+    the fetched ones are also ``rapid.decode``'s ``kv_blocks_read``).  With a
     held share of experts the routing is read back with the tokens:
     ``rapid.prefill`` and ``rapid.decode`` carry its attributes
     (``_routed``) and the counters ``moe_pairs_routed`` (tokens x top_k x layers) and
@@ -587,9 +590,16 @@ class DeviceExecutor(Executor):
         live = slots - self._row_rid.count(None)
         obs.count("decode_slot_steps_live", live)
         obs.count("decode_slot_steps_free", slots - live)
+        read = grid = 0
+        for pos in range(self.cfg.period):
+            r, g = ops.dense_blocks(self.cache[f"pos{pos}"]["k"],
+                                    self._lens + 1, self.cfg.window_at(pos))
+            read, grid = read + r, grid + g
+        obs.count("decode_kv_blocks_read", read)
+        obs.count("decode_kv_blocks_grid", grid)
         w = self.worker
-        with obs.span("rapid.decode", worker=w, live=live,
-                      slots=slots) as sp:
+        with obs.span("rapid.decode", worker=w, live=live, slots=slots,
+                      kv_blocks_read=read) as sp:
             with obs.span("rapid.put", worker=w):
                 tokens = self._put(self._tokens)
                 lens = self._put(self._lens)

@@ -12,14 +12,15 @@ from __future__ import annotations
 from typing import Optional
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import flash_prefill as _fp
 from repro.kernels import paged_attention as _pa
 from repro.kernels import ssm_scan as _ssm
 from repro.kernels import unified_pd as _updk
 
-# Pages of a dense slot cache: this many tokens on the lanes, where the
-# slot length is a multiple of it.
+# Pages of a dense slot cache that ``kv_append`` rewrites: this many
+# tokens on the lanes, where the slot length is a multiple of it.
 DENSE_PAGE = 128
 
 
@@ -47,25 +48,27 @@ def paged_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
 
 def dense_page_size(seq: int, page: int = DENSE_PAGE) -> int:
     """``page`` where it divides ``seq``, else the whole slot: a page is
-    the lane dim of the kernel's block, so a multiple of 128 or the row."""
+    the lane dim of ``kv_append``'s block, so a multiple of 128 or the
+    row."""
     return page if seq % page == 0 else seq
 
 
 def paged_attention_dense(q, cache_k, cache_v, layer, seq_lens, *,
                           window: Optional[int] = None,
-                          page: int = DENSE_PAGE, interpret: bool = False):
+                          interpret: bool = False):
     """Decode attention over layer ``layer`` of a *dense slot* cache via
     the paged kernel.
 
     q (B,Hq,D); cache_k/v (P,Hkv,B,D,Sc), stacked over P layers; seq_lens
     (B,) valid tokens (for ring-buffer windows pass min(len, window) —
     all slots valid).  The kernel reads the stacked cache where it lies:
-    slot b is row b, its pages [b*np, (b+1)*np) in an identity block
-    table, and ``layer`` picks the layer.
+    slot b is row b, its pages of ``dense_block_tokens`` tokens
+    [b*np, (b+1)*np) in an identity block table, and ``layer`` picks the
+    layer.
     """
-    B, Sc = cache_k.shape[2], cache_k.shape[4]
-    page = dense_page_size(Sc, page)
-    n_pages = Sc // page
+    B = cache_k.shape[2]
+    page = dense_block_tokens(cache_k)
+    n_pages = cache_k.shape[4] // page
     tables = (jnp.arange(B)[:, None] * n_pages +
               jnp.arange(n_pages)[None, :]).astype(jnp.int32)
     lens = seq_lens.astype(jnp.int32)
@@ -73,6 +76,28 @@ def paged_attention_dense(q, cache_k, cache_v, layer, seq_lens, *,
         lens = jnp.minimum(lens, window)
     return _pa.paged_attention(q, cache_k, cache_v, tables, lens, layer,
                                page=page, interpret=interpret)
+
+
+def dense_block_tokens(cache) -> int:
+    """Tokens of a slot that one grid step of ``paged_attention_dense``
+    reads, for a stacked dense cache (P,Hkv,B,D,Sc) or its shape
+    struct."""
+    _, Hkv, _, D, Sc = cache.shape
+    return _pa.block_tokens(Sc, Hkv, D, jnp.dtype(cache.dtype).itemsize)
+
+
+def dense_blocks(cache, seq_lens, window: Optional[int] = None) -> tuple:
+    """(fetched, spanned): the K/V blocks ``paged_attention_dense``
+    copies and those its grid spans over all P layers of a stacked dense
+    cache (P,Hkv,B,D,Sc), for host-side ``seq_lens`` (B,) as that
+    function takes them.  Blocks past a slot's last live one are not
+    fetched."""
+    P, _, B, _, Sc = cache.shape
+    T = dense_block_tokens(cache)
+    n = np.asarray(seq_lens, np.int64)
+    if window is not None:
+        n = np.minimum(n, window)
+    return P * int((-(-n // T)).sum()), P * B * (Sc // T)
 
 
 def append_dense(cache_k, cache_v, k_new, v_new, layer, slot, *,

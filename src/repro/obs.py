@@ -42,7 +42,8 @@ _ids = itertools.count(1)
 # the served path's counters, present from the start
 COUNTERS = ("steps", "compiles", "prefill_tokens_valid",
             "prefill_tokens_padded", "decode_slot_steps_live",
-            "decode_slot_steps_free")
+            "decode_slot_steps_free", "decode_kv_blocks_read",
+            "decode_kv_blocks_grid")
 _counters: Dict[str, int] = dict.fromkeys(COUNTERS, 0)
 
 

@@ -100,6 +100,23 @@ def test_paged_attention_compiles(one_chip, arch):
 
 
 @pytest.mark.parametrize("arch", sorted(SERVED))
+def test_paged_attention_dense_compiles(one_chip, arch):
+    """Decode attention over the served stacked cache (every period's
+    layer, the benchmark's slots and slot length), in blocks of all KV
+    heads and ``dense_block_tokens`` tokens; Mellum2's window rings are
+    ``test_window_ring_decode_kernels_compile``'s."""
+    cfg = get_config(arch)
+    B, S = SERVED[arch]
+    cache = _sds((cfg.num_periods, cfg.num_kv_heads, B, cfg.head_dim, S),
+                 jnp.bfloat16, one_chip)
+    q = _sds((B, cfg.num_heads, cfg.head_dim), jnp.bfloat16, one_chip)
+    c = jax.jit(paged_attention_dense).lower(
+        q, cache, cache, _sds((), jnp.int32, one_chip),
+        _sds((B,), jnp.int32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("arch", sorted(SERVED))
 def test_kv_append_compiles(one_chip, arch):
     cfg = get_config(arch)
     B, S = SERVED[arch]

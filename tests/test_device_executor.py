@@ -17,6 +17,7 @@ from repro import obs
 from repro.config import ServeConfig, get_reduced_config
 from repro.core import DeviceExecutor, Request, State, drive, make_engine
 from repro.core.executor import prefill_buckets, prompt_ids
+from repro.kernels import ops
 from repro.models.transformer import forward
 from repro.serving import EventLoop, Gateway
 
@@ -187,6 +188,34 @@ def test_mellum_share_serves_past_the_window_and_matches_reference(cpu):
     assert obs.counter("moe_pairs_routed") == routed
     assert obs.counter("moe_pairs_held") == held
     assert 0 < held < routed
+
+
+def test_decode_counts_kv_blocks_fetched_and_spanned(cpu):
+    """Reduced Mellum2 in slots of 1024: a full layer's kernel reads
+    blocks of 512 tokens, two a slot, a window layer its ring of 32,
+    one a slot.  A decode step over prompts of 40 and 600 and a free
+    slot (which attends 1 token) fetches only the live blocks: 1 + 2 + 1
+    of 6 in a full layer, 3 of 3 in a window layer."""
+    cfg = get_reduced_config("mellum2-12b-a2.5b")
+    serve = ServeConfig(mode="rapid", chips=1, max_batch_slots=3,
+                        max_seq_len=1024, page_size=16)
+    ex = DeviceExecutor(cfg, serve, cpu, seed=3, interpret=True)
+    full = [f"pos{p}" for p in range(cfg.period) if not cfg.window_at(p)]
+    assert {n: ops.dense_block_tokens(c["k"]) for n, c in ex.cache.items()} \
+        == {n: 512 if n in full else 32 for n in ex.cache}
+    reqs = _requests([(40, 4), (600, 4)])
+    for r in reqs:
+        ex._prefill(r)
+    obs.reset()
+    ex._decode(reqs)
+    n_full = cfg.num_periods * len(full)
+    n_window = cfg.num_layers - n_full
+    read = n_full * (1 + 2 + 1) + n_window * 3
+    grid = n_full * 3 * 2 + n_window * 3
+    assert (obs.counter("decode_kv_blocks_read"),
+            obs.counter("decode_kv_blocks_grid")) == (read, grid)
+    [span] = [s for s in obs.spans() if s.name == "rapid.decode"]
+    assert span.attrs["kv_blocks_read"] == read
 
 
 def test_refuses_other_schedulers(cfg, serve, cpu):

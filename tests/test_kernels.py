@@ -4,9 +4,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.config import get_config
+from repro.kernels import ops, ref
 from repro.kernels.flash_prefill import flash_prefill
-from repro.kernels.paged_attention import kv_append, paged_attention
+from repro.kernels.paged_attention import (BLOCK_BYTES, block_tokens,
+                                           kv_append, kv_page,
+                                           paged_attention)
 from repro.kernels.ssm_scan import ssm_scan
 from repro.kernels.unified_pd import build_slot_schedule, unified_pd
 
@@ -87,32 +90,103 @@ def test_paged_attention_len_one(rng):
     np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.parametrize("L,layer,Hq,Hkv,D,page,npr", [
-    (3, 2, 4, 2, 32, 8, 4),         # rows of 4 pages, GQA
-    (2, 1, 4, 4, 64, 16, 2),        # MHA, D=64
-    (4, 0, 2, 1, 16, 8, 1),         # one page per row: a page pool
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.mark.parametrize("L,layer,Hq,Hkv,D,page,row,lens,window,dtype", [
+    pytest.param(3, 2, 4, 2, 32, 8, 32, (1, 11, 24), None, F32,
+                 id="3-2-4-2-32-8-4"),         # rows of 4 pages, GQA
+    pytest.param(2, 1, 4, 4, 64, 16, 32, (1, 19, 48), None, F32,
+                 id="2-1-4-4-64-16-2"),        # MHA, D=64
+    pytest.param(4, 0, 2, 1, 16, 8, 8, (1, 11, 24), None, F32,
+                 id="4-0-2-1-16-8-1"),         # one page per row: a pool
+    # dense slot caches through ops.paged_attention_dense: lengths of 1,
+    # ending mid-block and on a block's end; blocks of 128-512 tokens
+    pytest.param(2, 1, 4, 4, 64, None, 1024, (1, 300, 512, 1024), None,
+                 BF16, id="dense-mha-d64-bf16-T512"),
+    pytest.param(2, 0, 4, 4, 64, None, 1024, (1, 513, 1000, 1024), None,
+                 F32, id="dense-mha-d64-f32-T512"),
+    pytest.param(2, 1, 36, 36, 64, None, 512, (1, 130, 256, 512), None,
+                 BF16, id="dense-mha36-d64-bf16-T128"),
+    pytest.param(2, 1, 32, 4, 128, None, 1024, (1, 700, 1024, 3000), 1024,
+                 BF16, id="dense-g8-bf16-T512-ring"),
+    pytest.param(2, 1, 32, 4, 128, None, 1024, (1, 255, 256, 900), None,
+                 F32, id="dense-g8-f32-T256"),
+    pytest.param(2, 1, 24, 2, 128, None, 2048, (1, 1000, 1536, 2048), None,
+                 BF16, id="dense-g12-bf16-T512"),
+    pytest.param(2, 0, 24, 2, 128, None, 1024, (1, 1024, 600, 5000), 1024,
+                 F32, id="dense-g12-f32-T512-ring"),
 ])
-def test_paged_attention_stacked_layer(rng, L, layer, Hq, Hkv, D, page, npr):
-    """A stacked cache (L, Hkv, R, D, npr*page) read at ``layer`` equals
-    that layer's pages, unrolled to a (Hkv, R*npr, D, page) pool."""
-    B, R, mp = 3, 5, 3
+def test_paged_attention_stacked_layer(rng, L, layer, Hq, Hkv, D, page, row,
+                                       lens, window, dtype):
+    """A stacked cache (L, Hkv, R, D, row) read at ``layer`` equals that
+    layer's pages, unrolled to a (Hkv, R*row/page, D, page) pool.  With
+    no ``page`` it is a dense slot cache, slot b row b, read through
+    ``ops.paged_attention_dense`` in blocks of ``dense_block_tokens``
+    (a window's ring at ``min(len, window)``): each slot's row is one
+    page of the reference's pool."""
+    B = len(lens)
+    R = 5 if page else B
     ks = jax.random.split(rng, 3)
-    q = _rand(ks[0], (B, Hq, D), jnp.float32)
-    kc = _rand(ks[1], (L, Hkv, R, D, npr * page), jnp.float32)
-    vc = _rand(ks[2], (L, Hkv, R, D, npr * page), jnp.float32)
-    rs = np.random.RandomState(2)
-    tabs = jnp.asarray(np.stack(
-        [rs.permutation(R * npr)[:mp] for _ in range(B)]).astype(np.int32))
-    lens = jnp.array([1, page + 3, mp * page], jnp.int32)
-    out = paged_attention(q, kc, vc, tabs, lens, jnp.int32(layer),
-                          page=page, interpret=True)
+    q = _rand(ks[0], (B, Hq, D), dtype)
+    kc = _rand(ks[1], (L, Hkv, R, D, row), dtype)
+    vc = _rand(ks[2], (L, Hkv, R, D, row), dtype)
+    seq = jnp.array(lens, jnp.int32)
+    if page:
+        npr = row // page
+        rs = np.random.RandomState(2)
+        tabs = jnp.asarray(np.stack(
+            [rs.permutation(R * npr)[:3] for _ in range(B)]).astype(np.int32))
+        out = paged_attention(q, kc, vc, tabs, seq, jnp.int32(layer),
+                              page=page, interpret=True)
 
-    def pool(c):
-        return c[layer].reshape(Hkv, R, D, npr, page).transpose(
-            0, 1, 3, 2, 4).reshape(Hkv, R * npr, D, page)
+        def pool(c):
+            return c[layer].reshape(Hkv, R, D, npr, page).transpose(
+                0, 1, 3, 2, 4).reshape(Hkv, R * npr, D, page)
+    else:
+        assert row // ops.dense_block_tokens(kc) > 1
+        out = ops.paged_attention_dense(q, kc, vc, jnp.int32(layer), seq,
+                                        window=window, interpret=True)
+        tabs = jnp.arange(B, dtype=jnp.int32)[:, None]
+        seq = jnp.minimum(seq, window or row)
 
-    want = ref.paged_attention(q, pool(kc), pool(vc), tabs, lens)
-    np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+        def pool(c):
+            return c[layer]
+
+    want = ref.paged_attention(q, pool(kc), pool(vc), tabs, seq)
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               want.astype(jnp.float32), **TOL[dtype])
+
+
+# the benchmark's dense decode caches: (config, tokens a slot's row
+# holds, the block the kernel reads)
+SERVED_ROWS = [("starcoder2-3b", 2048, 512), ("minicpm-2b", 2048, 128),
+               ("mellum2-12b-a2.5b", 8192, 512),
+               ("mellum2-12b-a2.5b", 1024, 512)]     # its window rings
+
+
+@pytest.mark.parametrize("arch,row,block", SERVED_ROWS)
+def test_block_rule_at_served_shapes(arch, row, block):
+    """A grid step reads a multiple of 128 tokens that divides the row,
+    at most 1 MiB of K and V past 128; the clamped index map fetches
+    each slot's live blocks, never one past its last, once each."""
+    cfg = get_config(arch)
+    Hkv, D = cfg.num_kv_heads, cfg.head_dim
+    T = block_tokens(row, Hkv, D, 2)
+    assert T == block and T % 128 == 0 and row % T == 0
+    assert T == 128 or T * 2 * Hkv * D * 2 <= BLOCK_BYTES
+    nb = row // T
+    n = np.arange(1, row + 1)                     # one slot per length
+    tab = (np.arange(len(n))[:, None] * nb + np.arange(nb)).astype(np.int32)
+    b, j = np.arange(len(n))[:, None], np.arange(nb)[None, :]
+    idx = kv_page(b, j, tab, n, np.array([1]), page=T, per_row=nb)
+    lay, _, r, _, col = (np.broadcast_to(np.asarray(x), (len(n), nb))
+                         for x in idx)
+    last = (n - 1) // T
+    assert (lay == 1).all() and (r == b).all()
+    assert (col == np.minimum(j, last[:, None])).all()
+    assert ((col != np.roll(col, 1, axis=1))[:, 1:].sum(1) + 1
+            == -(-n // T)).all()
 
 
 # ---------------------------------------------------------------------------
